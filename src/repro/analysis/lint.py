@@ -24,12 +24,18 @@ from __future__ import annotations
 
 import ast
 import os
+import tomllib
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Iterable, Optional
 
-from . import toml_lite
 from .rules import RULES, Finding, dotted_name
+
+
+def load_toml(path: str) -> dict:
+    """Parse a TOML file (``baseline.toml``, ``pyproject.toml``)."""
+    with open(path, "rb") as f:
+        return tomllib.load(f)
 
 JITTABLE_OPS = {
     "insert",
@@ -396,7 +402,7 @@ class BaselineEntry:
 def load_baseline(path: str) -> list[BaselineEntry]:
     if not os.path.exists(path):
         return []
-    data = toml_lite.load_path(path)
+    data = load_toml(path)
     entries = []
     for i, raw in enumerate(data.get("allow", [])):
         try:
@@ -475,7 +481,7 @@ def load_config(root: str = ".") -> LintConfig:
     cfg = LintConfig()
     pj = os.path.join(root, "pyproject.toml")
     if os.path.exists(pj):
-        data = toml_lite.load_path(pj)
+        data = load_toml(pj)
         sec = data.get("tool", {}).get("repro-lint", {})
         cfg.paths = list(sec.get("paths", cfg.paths))
         cfg.exclude = list(sec.get("exclude", cfg.exclude))

@@ -221,53 +221,57 @@ def _planes(total):
     return z, z, z, z
 
 
+# window and item blocks are lane-dense rows of 128 slots
+_W = 128
+
+
 def _drive_qf_probe():
     from repro.kernels.qf_probe import qf_probe_tiles
 
-    rem, occ, shf, con = _planes(64)
-    fq = jnp.arange(8, dtype=jnp.int32)
+    rem, occ, shf, con = _planes(8 * _W)
+    fq = jnp.arange(8, dtype=jnp.int32) * 64
     fr = jnp.zeros((8,), jnp.int32)
-    qf_probe_tiles(rem, occ, shf, con, fq, fr, tile_t=4, wblk=8, interpret=True)
+    qf_probe_tiles(rem, occ, shf, con, fq, fr, tile_t=4, wblk=_W, interpret=True)
 
 
 def _drive_qf_build():
     from repro.kernels.qf_build import qf_build_planes
 
-    pos = jnp.arange(6, dtype=jnp.int32)
+    pos = jnp.arange(6, dtype=jnp.int32) * 50
     fr = jnp.ones((6,), jnp.int32)
     mb = jnp.zeros((6,), jnp.int32)
-    qf_build_planes(pos, fr, mb, total_slots=32, block_s=8, interpret=True)
+    qf_build_planes(pos, fr, mb, total_slots=4 * _W, block_s=_W, interpret=True)
 
 
 def _drive_bloom_probe():
     from repro.kernels.bloom_block import bloom_probe_tiles
 
-    cells = jnp.zeros((64,), jnp.int32)
+    cells = jnp.zeros((8 * _W,), jnp.int32)
     idx = jnp.sort(
-        (jnp.arange(24, dtype=jnp.int32).reshape(8, 3) * 2) % 64, axis=1
+        (jnp.arange(24, dtype=jnp.int32).reshape(8, 3) * 40) % (8 * _W), axis=1
     )
     idx = idx[jnp.argsort(jnp.min(idx, axis=1))]
-    bloom_probe_tiles(cells, idx, tile_t=4, wblk=8, interpret=True)
+    bloom_probe_tiles(cells, idx, tile_t=4, wblk=_W, interpret=True)
 
 
 def _drive_bloom_count():
     from repro.kernels.bloom_block import bloom_count_tiles
 
-    idx = jnp.sort(jnp.arange(10, dtype=jnp.int32) * 5)
-    bloom_count_tiles(idx, ncells=64, block_s=8, interpret=True)
+    idx = jnp.sort(jnp.arange(10, dtype=jnp.int32) * 45)
+    bloom_count_tiles(idx, ncells=4 * _W, block_s=_W, interpret=True)
 
 
 def _drive_cascade_probe():
     from repro.kernels.cascade_probe import cascade_probe_tiles
 
-    planes = [_planes(64), _planes(128)]
-    fq0 = jnp.arange(8, dtype=jnp.int32)
+    planes = [_planes(4 * _W), _planes(8 * _W)]
+    fq0 = jnp.arange(8, dtype=jnp.int32) * 60
     cascade_probe_tiles(
         planes,
         [fq0, fq0 * 2],
         [jnp.zeros((8,), jnp.int32)] * 2,
         tile_t=4,
-        wblk=8,
+        wblk=_W,
         interpret=True,
     )
 
@@ -275,11 +279,11 @@ def _drive_cascade_probe():
 def _drive_fuse_probe():
     from repro.kernels.fuse_probe import fuse_probe_tiles
 
-    table = jnp.zeros((64,), jnp.int32)
-    p0 = jnp.arange(8, dtype=jnp.int32)
+    table = jnp.zeros((8 * _W,), jnp.int32)
+    p0 = jnp.arange(8, dtype=jnp.int32) * 60
     fuse_probe_tiles(
         table, p0, p0 + 1, p0 + 2, jnp.zeros((8,), jnp.uint32),
-        tile_t=4, wblk=8, interpret=True,
+        tile_t=4, wblk=_W, interpret=True,
     )
 
 

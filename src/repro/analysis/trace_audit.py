@@ -9,7 +9,7 @@ per op:
   concretization error: the op is host-composed by design, e.g. the
   frozen cascade's peeling merge-down), ``unbound`` (family does not
   register the op), or ``unsupported`` (config-level refusal).
-- **eqns** — recursive equation count (through pjit/cond/scan/switch
+- **eqns** — recursive equation count (through jit/cond/scan/switch
   sub-jaxprs), the audit's size fingerprint: a silent fallback from one
   fused program to an unrolled host loop shows up as a blow-up here.
 - **prims** — recursive primitive histogram.  Callback and transfer
@@ -101,7 +101,7 @@ def _count_jaxpr(jaxpr) -> tuple[int, dict[str, int]]:
 
 
 def _sub_jaxprs(value):
-    from jax.extend import core as jex_core  # jax >= 0.4.16
+    from jax.extend import core as jex_core
 
     jaxpr_types = (jex_core.Jaxpr, jex_core.ClosedJaxpr)
     if isinstance(value, jaxpr_types):
@@ -270,10 +270,8 @@ def render_summary(current: dict) -> str:
         for op, entry in sorted(ops.items()):
             extra = ""
             if entry["status"] == "traced":
-                pjits = sum(
-                    n for p, n in entry["prims"].items() if p in ("pjit", "xla_call")
-                )
-                extra = f" eqns={entry['eqns']} pjit={pjits}"
+                jits = entry["prims"].get("jit", 0)
+                extra = f" eqns={entry['eqns']} jit={jits}"
             lines.append(f"  {fam + '.' + op:40s} {entry['status']}{extra}")
     return "\n".join(lines)
 

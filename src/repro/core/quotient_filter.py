@@ -44,6 +44,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import scan
 from .fingerprint import fingerprint
 
 INT32_MAX = jnp.int32(2**31 - 1)
@@ -123,7 +124,9 @@ def _pad_sort(fq: jnp.ndarray, fr: jnp.ndarray, valid: jnp.ndarray):
     """Sort (fq, fr) lexicographically, pushing invalid entries to the end."""
     fq = jnp.where(valid, fq, INT32_MAX)
     fr = jnp.where(valid, fr, UINT32_MAX)
-    fq, fr = jax.lax.sort((fq, fr), num_keys=2)
+    # equal (fq, fr) pairs are identical, so an unstable sort returns
+    # the same arrays (and compiles far faster on TPU)
+    fq, fr = jax.lax.sort((fq, fr), num_keys=2, is_stable=False)
     return fq, fr
 
 
@@ -147,22 +150,39 @@ def build_sorted(cfg: QFConfig, fq: jnp.ndarray, fr: jnp.ndarray, n) -> QFState:
     #                                = i + cummax(fq[i] - i)          (scan)
     # The padding sentinel must stay out of the subtraction: -INT32_MAX - idx
     # wraps for idx >= 2, so the difference is formed for valid rows only.
-    pos = idx + jax.lax.cummax(jnp.where(valid, fq - idx, -INT32_MAX))
+    pos = idx + scan.cummax(jnp.where(valid, fq - idx, -INT32_MAX))
     overflow = jnp.any(valid & (pos >= t))
     spos = jnp.where(valid, pos, INT32_MAX)  # scatter-drop for padding
 
     con_bits = valid & (idx > 0) & (fq == jnp.roll(fq, 1))
     shf_bits = valid & (pos != fq)
 
-    rem = jnp.zeros((t,), jnp.uint32).at[spos].set(fr, mode="drop")
-    shf = jnp.zeros((t,), jnp.bool_).at[spos].set(shf_bits, mode="drop")
-    con = jnp.zeros((t,), jnp.bool_).at[spos].set(con_bits, mode="drop")
-    occ = (
-        jnp.zeros((t,), jnp.bool_)
-        .at[jnp.where(valid, fq, INT32_MAX)]
-        .set(True, mode="drop")
-    )
+    # positions and quotients ascend (padding last), so every scatter
+    # here is a sorted one
+    put = dict(mode="drop", indices_are_sorted=True)
+    rem = jnp.zeros((t,), jnp.uint32).at[spos].set(fr, **put)
+    shf = jnp.zeros((t,), jnp.bool_).at[spos].set(shf_bits, **put)
+    con = jnp.zeros((t,), jnp.bool_).at[spos].set(con_bits, **put)
+    occ = jnp.zeros((t,), jnp.bool_).at[jnp.where(valid, fq, INT32_MAX)].set(True, **put)
     return QFState(rem=rem, occ=occ, shf=shf, con=con, n=nn, overflow=overflow)
+
+
+def _compact(keep: jnp.ndarray, values: jnp.ndarray, fill) -> jnp.ndarray:
+    """Stream compaction: ``values[keep]`` moved to the front, ``fill``
+    after them.
+
+    Kept element k (0-based rank) lands at odd index ``2k + 1`` of a
+    twice-as-long buffer and every dropped element at the even index
+    just before the next kept one, so the scatter indices ascend — a
+    sorted scatter, which the TPU compiles and runs far faster than an
+    arbitrary one.  The odd half is the compacted stream.
+    """
+    t = keep.shape[0]
+    kept = keep.astype(jnp.int32)
+    dest = 2 * scan.cumsum(kept) - kept
+    out = jnp.full((2 * t,), fill, values.dtype)
+    out = out.at[dest].set(values, mode="drop", indices_are_sorted=True)
+    return out[1::2]
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -177,18 +197,17 @@ def extract(cfg: QFConfig, state: QFState):
     nonempty = state.occ | state.shf  # continuation implies shifted
     run_start = nonempty & ~state.con
     # run_id: 1-indexed run ordinal for every slot in a run
-    run_id = jnp.cumsum(run_start.astype(jnp.int32))
+    run_id = scan.cumsum(run_start.astype(jnp.int32))
     # bucket of the j-th run = index of the j-th set is_occupied bit
-    occ_cum = jnp.cumsum(state.occ.astype(jnp.int32))
-    # searchsorted(occ_cum, j, 'left') == first index with occ_cum >= j
-    bucket_of_run = jnp.searchsorted(occ_cum, run_id, side="left").astype(jnp.int32)
+    # (the select half of rank/select; a missing rank reads t, as
+    # searchsorted(occ_cum, j, 'left') would)
+    slot = jnp.arange(t, dtype=jnp.int32)
+    select = _compact(state.occ, slot, t)
+    bucket_of_run = select[jnp.clip(run_id - 1, 0, t - 1)]
     fq_slot = jnp.where(nonempty, bucket_of_run, INT32_MAX)
     fr_slot = jnp.where(nonempty, state.rem, UINT32_MAX)
-    # compact: scatter valid entries to their rank
-    dest = jnp.cumsum(nonempty.astype(jnp.int32)) - 1
-    dest = jnp.where(nonempty, dest, INT32_MAX)
-    fq_out = jnp.full((t,), INT32_MAX, jnp.int32).at[dest].set(fq_slot, mode="drop")
-    fr_out = jnp.full((t,), UINT32_MAX, jnp.uint32).at[dest].set(fr_slot, mode="drop")
+    fq_out = _compact(nonempty, fq_slot, INT32_MAX)
+    fr_out = _compact(nonempty, fr_slot, UINT32_MAX)
     return fq_out, fr_out, state.n
 
 
@@ -319,14 +338,21 @@ def contains(cfg: QFConfig, state: QFState, keys: jnp.ndarray, window: int = 256
 # ---------------------------------------------------------------------------
 
 
-def merge_sorted_with(cfg: QFConfig, state: QFState, fq, fr, k, build) -> QFState:
-    """insert_sorted body with a pluggable build pass (reference or kernel)."""
+def merge_sorted_with(
+    cfg: QFConfig, state: QFState, fq, fr, k, build, batch_valid=None
+) -> QFState:
+    """insert_sorted body with a pluggable build pass (reference or kernel).
+
+    The batch is sorted together with the table, so it need not be
+    sorted itself: ``batch_valid`` marks its ``k`` valid rows (default:
+    the first ``k``).
+    """
     qs, rs, n = extract(cfg, state)
     allq = jnp.concatenate([qs, fq])
     allr = jnp.concatenate([rs, fr])
-    valid = jnp.concatenate(
-        [jnp.arange(qs.shape[0]) < n, jnp.arange(fq.shape[0]) < jnp.asarray(k)]
-    )
+    if batch_valid is None:
+        batch_valid = jnp.arange(fq.shape[0]) < jnp.asarray(k)
+    valid = jnp.concatenate([jnp.arange(qs.shape[0]) < n, batch_valid])
     allq, allr = _pad_sort(allq, allr, valid)
     new = build(cfg, allq, allr, n + jnp.asarray(k, jnp.int32))
     return new._replace(overflow=new.overflow | state.overflow)
@@ -359,9 +385,12 @@ def delete_sorted(cfg: QFConfig, state: QFState, fq, fr, k) -> QFState:
     qs, rs, n = extract(cfg, state)
     idx = jnp.arange(qs.shape[0], dtype=jnp.int32)
     valid = idx < n
-    # occurrence rank of element i among equal fingerprints
-    first = lex_searchsorted(qs, rs, qs, rs, "left")
-    rank = idx - first
+    # occurrence rank of element i among equal fingerprints: distance to
+    # the start of its run in the sorted stream
+    new_run = jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), (qs[1:] != qs[:-1]) | (rs[1:] != rs[:-1])]
+    )
+    rank = idx - scan.cummax(jnp.where(new_run, idx, 0))
     # how many copies of this fingerprint are being deleted
     dlo = lex_searchsorted(fq, fr, qs, rs, "left")
     dhi = lex_searchsorted(fq, fr, qs, rs, "right")
@@ -369,7 +398,9 @@ def delete_sorted(cfg: QFConfig, state: QFState, fq, fr, k) -> QFState:
         dlo, jnp.asarray(k, jnp.int32)
     )
     keep = valid & (rank >= ndel)
-    qs2, rs2 = _pad_sort(qs, rs, keep)
+    # dropping entries keeps the stream sorted: compact, no re-sort
+    qs2 = _compact(keep, qs, INT32_MAX)
+    rs2 = _compact(keep, rs, UINT32_MAX)
     return build_sorted(cfg, qs2, rs2, jnp.sum(keep, dtype=jnp.int32))
 
 

@@ -26,15 +26,9 @@ from . import quotient_filter as qf
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: top-level ``jax.shard_map`` (>=0.5,
-    ``check_vma=``) vs ``jax.experimental.shard_map`` (0.4.x, ``check_rep=``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def _dispatch_capacity(cfg: "ShardedQFConfig", per_shard: int) -> int:

@@ -69,11 +69,9 @@ def insert_fingerprints(
     core: qf.QFConfig, backend: str, state: qf.QFState, fq, fr, valid
 ) -> qf.QFState:
     """Merge a validity-masked fingerprint batch into ``state``."""
-    fq, fr = qf._pad_sort(fq, fr, valid)
     k = jnp.sum(valid, dtype=jnp.int32)
-    if backend == "pallas":
-        return qf.merge_sorted_with(core, state, fq, fr, k, kops.build_sorted)
-    return qf.insert_sorted(core, state, fq, fr, k)
+    build = kops.build_sorted if backend == "pallas" else qf.build_sorted
+    return qf.merge_sorted_with(core, state, fq, fr, k, build, batch_valid=valid)
 
 
 def insert_keys(

@@ -20,6 +20,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import quotient_filter as qf
 from repro.core import sharded_filter as sf
@@ -50,13 +51,16 @@ class ShardedQFilterConfig(NamedTuple):
 
 @functools.lru_cache(maxsize=None)
 def _mesh(n_shards: int, axis: str):
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh((n_shards,), (axis,))
-    # jax < 0.4.35
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
+    # Auto axes: the per-shard vmap'd structural ops (merge, grow,
+    # shrink) run outside shard_map and must see plain arrays
+    return jax.make_mesh(
+        (n_shards,), (axis,), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
-    return Mesh(mesh_utils.create_device_mesh((n_shards,)), (axis,))
+
+def _on_mesh(cfg) -> NamedSharding:
+    """Shard the stacked per-shard state's leading dim over the mesh."""
+    return NamedSharding(_mesh(cfg.n_shards, cfg.axis), PartitionSpec(cfg.axis))
 
 
 @functools.lru_cache(maxsize=None)
@@ -87,7 +91,9 @@ def make(**spec):
         raise ValueError(
             f"n_shards={cfg.n_shards} does not divide {len(jax.devices())} devices"
         )
-    return cfg, sf.empty(cfg.core)
+    # each device materializes only its own shard
+    state = jax.jit(lambda: sf.empty(cfg.core), out_shardings=_on_mesh(cfg))()
+    return cfg, state
 
 
 def insert(cfg: ShardedQFilterConfig, state, keys, k=None):
@@ -239,11 +245,7 @@ def shrink(cfg: ShardedQFilterConfig, state):
     # the result leaves inherit the old (wider) device placement; commit
     # them onto the halved mesh so the shard_map'd step functions see a
     # consistent layout
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    sharding = NamedSharding(
-        _mesh(new_cfg.n_shards, new_cfg.axis), PartitionSpec(new_cfg.axis)
-    )
+    sharding = _on_mesh(new_cfg)
     return new_cfg, jax.tree.map(lambda x: jax.device_put(x, sharding), merged)
 
 

@@ -13,8 +13,8 @@ window-prefetch scheme rewards:
 * **insert** — the write side mirrors ``qf_build``: ALL k*B touched
   cell indices are sorted, so the items landing in an S-cell output
   tile are one contiguous range whose item-block is scalar-prefetched;
-  the kernel reduces a (2S x S) one-hot match matrix into per-cell hit
-  COUNTS.  Counts compose with any cell plane: ``cells + counts``
+  the kernel reduces one-hot match matrices (``qf_build.scatter_rows``)
+  into per-cell hit COUNTS.  Counts compose with any cell plane: ``cells + counts``
   (counting), ``cells | (counts > 0)`` (plain bits), ``cells - counts``
   (counting delete) — and because the aggregation is commutative, tiles
   whose bins are denser than the item window simply fall back to a
@@ -28,7 +28,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import dispatch
+from . import dispatch, lanes
+from .qf_build import launch_tiles as _launch_tiles
+from .qf_build import scatter_rows, tile_base
 
 
 def _make_probe_kernel(k: int):
@@ -37,20 +39,14 @@ def _make_probe_kernel(k: int):
         blk_ref, wbase_ref, cell_a, cell_b = refs[:4]
         idx_refs = refs[4 : 4 + k]
         hit_o = refs[4 + k]
-        t = pl.program_id(0)
+        base = wbase_ref[pl.program_id(0)]
+        w = jnp.concatenate([cell_a[...], cell_b[...]], axis=0)  # cells
 
-        T = idx_refs[0].shape[1]
-        WT = 2 * cell_a.shape[1]
-        w = jnp.concatenate([cell_a[0, :], cell_b[0, :]])  # (WT,) cells
-        base = wbase_ref[t]
-        js = jax.lax.broadcasted_iota(jnp.int32, (T, WT), 1)
-
-        hit = jnp.ones((T,), jnp.bool_)
+        hit = None
         for j in range(k):
-            rel = idx_refs[j][0, :] - base
-            val = jnp.sum(jnp.where(js == rel[:, None], w[None, :], 0), axis=1)
-            hit = hit & (val > 0)
-        hit_o[0, :] = hit.astype(jnp.int32)
+            val = lanes.gather(w, lanes.row_to_col(idx_refs[j][0]) - base)
+            hit = val > 0 if hit is None else hit & (val > 0)
+        hit_o[0] = lanes.col_to_row(hit.astype(jnp.int32))
 
     return kernel
 
@@ -65,8 +61,8 @@ def bloom_probe_tiles(
 ):
     """AND-of-k probe of bin-sorted queries. Returns (hit, ovf) int32 (B,).
 
-    ``cells`` is the int32 cell plane; ``idx_sorted`` is (B, k) cell
-    indices with rows ordered by their minimum index (bin order) and B a
+    ``cells`` is the cell plane; ``idx_sorted`` is (B, k) cell indices
+    with rows ordered by their minimum index (bin order) and B a
     multiple of ``tile_t``.  Tiles whose index span exceeds the 2*wblk
     window report overflow for all their queries.
     """
@@ -81,46 +77,51 @@ def bloom_probe_tiles(
     mx = jnp.max(idx3, axis=(1, 2))
     blk, wbase, fits = dispatch.window_base(mn, mx, total, wblk)
 
-    win = lambda off: pl.BlockSpec((1, wblk), lambda t, blk, wbase: (blk[t] + off, 0))
-    qspec = pl.BlockSpec((1, tile_t), lambda t, blk, wbase: (t, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_tiles,),
-        in_specs=[win(0), win(1)] + [qspec] * k,
-        out_specs=[qspec],
-    )
+    qspec = dispatch.query_spec(tile_t)
     idx_args = [
-        idx3[:, :, j].reshape(n_tiles, tile_t) for j in range(k)
+        dispatch.query_tiles(idx_sorted[:, j], tile_t) for j in range(k)
     ]
-    (hit2,) = pl.pallas_call(
-        _make_probe_kernel(k),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_tiles, tile_t), jnp.int32)],
-        interpret=interpret,
-    )(blk, wbase, cells2, cells2, *idx_args)
 
-    ovf2 = jnp.broadcast_to((~fits[:, None]).astype(jnp.int32), hit2.shape)
-    return hit2.reshape(B), ovf2.reshape(B)
+    def launch(s, n):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n,),
+            in_specs=[dispatch.window_spec(wblk, 0), dispatch.window_spec(wblk, 1)]
+            + [qspec] * k,
+            out_specs=[qspec],
+        )
+        return pl.pallas_call(
+            _make_probe_kernel(k),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)],
+            interpret=interpret,
+        )(
+            blk[s : s + n],
+            wbase[s : s + n],
+            cells2,
+            cells2,
+            *(x[s : s + n] for x in idx_args),
+        )
+
+    (hit3,) = dispatch.concat_launches(
+        launch(s, n) for s, n in dispatch.launches(n_tiles, 2)
+    )
+
+    ovf3 = jnp.broadcast_to((~fits[:, None, None]).astype(jnp.int32), hit3.shape)
+    return hit3.reshape(B), ovf3.reshape(B)
 
 
-def _count_kernel(blk_ref, idx_a, idx_b, cnt_o):
-    t = pl.program_id(0)
-    S = cnt_o.shape[1]
-    base = t * S
-
-    w_idx = jnp.concatenate([idx_a[0, :], idx_b[0, :]])  # (2S,)
-    rel = w_idx - base  # outside [0, S) contributes nothing
-    cols = jax.lax.broadcasted_iota(jnp.int32, (2 * S, S), 1)
-    hit = rel[:, None] == cols  # (2S, S)
-    cnt_o[0, :] = jnp.sum(hit.astype(jnp.int32), axis=0)
+def _count_kernel(blk_ref, first_ref, idx_a, idx_b, cnt_o):
+    base = tile_base(first_ref, cnt_o)
+    idx = jnp.concatenate([idx_a[...], idx_b[...]], axis=0)
+    scatter_rows(idx, [jnp.ones_like(idx)], [cnt_o], base)
 
 
 def bloom_count_tiles(
     idx_flat_sorted: jnp.ndarray,
     ncells: int,
     *,
-    block_s: int = 512,
+    block_s: int = 1024,
     interpret: bool = True,
 ):
     """Aggregate ascending cell indices into per-cell counts, tiled.
@@ -130,7 +131,7 @@ def bloom_count_tiles(
     tile's item range exceeded its two prefetched item blocks (denser
     than 2*block_s items — the caller recounts those tiles by scatter).
     Sentinel indices (>= n_tiles * block_s, e.g. INT32_MAX for masked
-    keys) never land in any tile.
+    keys) never land in any tile.  ``block_s`` is a multiple of 128.
     """
     S = block_s
     n_tiles = -(-ncells // S)
@@ -140,7 +141,7 @@ def bloom_count_tiles(
     idx_p = jnp.concatenate(
         [idx_flat_sorted, jnp.full((pad,), jnp.int32(2**31 - 1))]
     )
-    idx2 = idx_p.reshape(n_blocks, S)
+    idx2 = idx_p.reshape(-1, lanes.LANES)
 
     tile_base = jnp.arange(n_tiles, dtype=jnp.int32) * S
     starts = jnp.searchsorted(idx_p, tile_base)
@@ -148,19 +149,8 @@ def bloom_count_tiles(
     blk = jnp.minimum(starts // S, n_blocks - 2).astype(jnp.int32)
     fits = ends <= (blk + 2) * S
 
-    win = lambda off: pl.BlockSpec((1, S), lambda t, blk: (blk[t] + off, 0))
-    out = pl.BlockSpec((1, S), lambda t, blk: (t, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_tiles,),
-        in_specs=[win(0), win(1)],
-        out_specs=[out],
+    (cnt2,) = dispatch.concat_launches(
+        _launch_tiles(_count_kernel, blk, [idx2], S, 1, interpret, s, n)
+        for s, n in dispatch.launches(n_tiles, 1)
     )
-    (cnt2,) = pl.pallas_call(
-        _count_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_tiles, S), jnp.int32)],
-        interpret=interpret,
-    )(blk, idx2, idx2)
     return cnt2.reshape(n_tiles * S), fits

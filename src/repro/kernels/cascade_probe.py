@@ -11,8 +11,9 @@ fuses the whole unfrozen stack into ONE grid over the sorted queries:
 * The grid is one program per query tile.  For each of the L levels the
   program sees that level's own 2*wblk-slot window (aligned start
   scalar-prefetched per (tile, level), exactly ``qf_probe``'s
-  two-consecutive-block scheme, just L of them), and runs the shared
-  branch-free cluster decode (``qf_probe.window_decode``) per level.
+  two-consecutive-block scheme and lane-dense layout, just L of them),
+  and runs the shared branch-free cluster decode
+  (``qf_probe.window_decode``) per level.
 * Per-query results come back as two int32 *bitmasks* (hit / overflow,
   bit l = level l), so the launch has a fixed two-output shape for any
   static depth L.
@@ -34,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import dispatch
+from . import dispatch, lanes
 from .qf_probe import window_decode
 
 
@@ -42,42 +43,34 @@ def _make_kernel(L: int):
     """Kernel body for a static stack depth L.
 
     Ref layout (positional): 2L scalar-prefetch refs (blk_l, wbase_l
-    interleaved), then 8 window refs per level (rem/occ/shf/con, two
+    interleaved), then 4 window refs per level (rem/meta, two
     consecutive blocks each), then fq/fr query tiles per level, then
     the two bitmask outputs.
     """
 
     def kernel(*refs):
         scalars = refs[: 2 * L]
-        planes = refs[2 * L : 10 * L]
-        queries = refs[10 * L : 12 * L]
-        hit_o, ovf_o = refs[12 * L], refs[12 * L + 1]
+        planes = refs[2 * L : 6 * L]
+        queries = refs[6 * L : 8 * L]
+        hit_o, ovf_o = refs[8 * L], refs[8 * L + 1]
         t = pl.program_id(0)
 
-        T = queries[0].shape[1]
-        hitm = jnp.zeros((T,), jnp.int32)
-        ovfm = jnp.zeros((T,), jnp.int32)
+        T = queries[0].shape[-1]
+        hitm = jnp.zeros((T, 1), jnp.int32)
+        ovfm = jnp.zeros((T, 1), jnp.int32)
         for lvl in range(L):
-            rem_a, rem_b, occ_a, occ_b, shf_a, shf_b, con_a, con_b = planes[
-                8 * lvl : 8 * (lvl + 1)
-            ]
-            w_rem = jnp.concatenate([rem_a[0, :], rem_b[0, :]])
-            w_occ = jnp.concatenate([occ_a[0, :], occ_b[0, :]]) > 0
-            w_shf = jnp.concatenate([shf_a[0, :], shf_b[0, :]]) > 0
-            w_con = jnp.concatenate([con_a[0, :], con_b[0, :]]) > 0
+            rem_a, rem_b, meta_a, meta_b = planes[4 * lvl : 4 * (lvl + 1)]
             present, ovf = window_decode(
-                w_rem,
-                w_occ,
-                w_shf,
-                w_con,
-                queries[2 * lvl][0, :],
-                queries[2 * lvl + 1][0, :],
+                jnp.concatenate([rem_a[...], rem_b[...]], axis=0),
+                jnp.concatenate([meta_a[...], meta_b[...]], axis=0),
+                lanes.row_to_col(queries[2 * lvl][0]),
+                lanes.row_to_col(queries[2 * lvl + 1][0]),
                 scalars[2 * lvl + 1][t],
             )
             hitm = hitm | (present.astype(jnp.int32) << lvl)
             ovfm = ovfm | (ovf.astype(jnp.int32) << lvl)
-        hit_o[0, :] = hitm
-        ovf_o[0, :] = ovfm
+        hit_o[0] = lanes.col_to_row(hitm)
+        ovf_o[0] = lanes.col_to_row(ovfm)
 
     return kernel
 
@@ -93,8 +86,8 @@ def cascade_probe_tiles(
 ):
     """Probe all QF levels of a cascade in one fused grid.
 
-    ``level_planes`` is a list of ``(rem, occ, shf, con)`` int32 plane
-    tuples (one per level, arbitrary per-level sizes); ``fq_levels`` /
+    ``level_planes`` is a list of ``(rem, occ, shf, con)`` plane tuples
+    (one per level, arbitrary per-level sizes); ``fq_levels`` /
     ``fr_levels`` hold each level's quotient/remainder view of the SAME
     canonically sorted, tile-padded query batch (so every ``fq_levels[l]``
     is ascending).  Returns ``(hit_mask, ovf_mask)`` int32 (B,) bitmask
@@ -111,51 +104,49 @@ def cascade_probe_tiles(
     plane_args = []
     in_specs = []
     tile_mask = jnp.zeros((n_tiles,), jnp.int32)
-
-    def win(lvl, off):
-        # index_map sees (t, s_0 .. s_{2L-1}); blk of level l is s[2l]
-        return pl.BlockSpec(
-            (1, wblk), lambda t, *s, lvl=lvl, off=off: (s[2 * lvl][t] + off, 0)
-        )
-
-    qspec = pl.BlockSpec((1, tile_t), lambda t, *s: (t, 0))
+    query_args = []
 
     for lvl, (rem, occ, shf, con) in enumerate(level_planes):
-        total = rem.shape[0]
-        fq2 = fq_levels[lvl].reshape(n_tiles, tile_t)
+        fq3 = dispatch.query_tiles(fq_levels[lvl], tile_t)
         blk, wbase, fits = dispatch.window_base(
-            fq2[:, 0], fq2[:, -1], total, wblk, margin=wblk // 4
+            fq3[:, 0, 0], fq3[:, 0, -1], rem.shape[0], wblk, margin=wblk // 4
         )
         scalars += [blk, wbase]
         tile_mask = tile_mask | ((~fits).astype(jnp.int32) << lvl)
-        for plane in (rem, occ, shf, con):
+        for plane in (rem, dispatch.meta_plane(occ, shf, con)):
             padded = dispatch.plane_blocks(plane, wblk)
             plane_args += [padded, padded]
-            in_specs += [win(lvl, 0), win(lvl, 1)]
+            # index_map sees (t, s_0 .. s_{2L-1}); blk of level l is s[2l]
+            in_specs += [
+                dispatch.window_spec(wblk, 0, 2 * lvl),
+                dispatch.window_spec(wblk, 1, 2 * lvl),
+            ]
+        query_args += [fq3, dispatch.query_tiles(fr_levels[lvl], tile_t)]
 
-    query_args = []
-    for lvl in range(L):
-        query_args += [
-            fq_levels[lvl].reshape(n_tiles, tile_t),
-            fr_levels[lvl].astype(jnp.int32).reshape(n_tiles, tile_t),
-        ]
-        in_specs += [qspec, qspec]
+    qspec = dispatch.query_spec(tile_t)
+    in_specs += [qspec, qspec] * L
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 * L,
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=[qspec, qspec],
+    def launch(s, n):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2 * L,
+            grid=(n,),
+            in_specs=in_specs,
+            out_specs=[qspec, qspec],
+        )
+        return pl.pallas_call(
+            _make_kernel(L),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)] * 2,
+            interpret=interpret,
+        )(
+            *(x[s : s + n] for x in scalars),
+            *plane_args,
+            *(x[s : s + n] for x in query_args),
+        )
+
+    hit3, ovf3 = dispatch.concat_launches(
+        launch(s, n) for s, n in dispatch.launches(n_tiles, 2 * L)
     )
-    hit2, ovf2 = pl.pallas_call(
-        _make_kernel(L),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, tile_t), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, tile_t), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*scalars, *plane_args, *query_args)
 
-    ovf2 = ovf2 | tile_mask[:, None]
-    return hit2.reshape(B), ovf2.reshape(B)
+    ovf3 = ovf3 | tile_mask[:, None, None]
+    return hit3.reshape(B), ovf3.reshape(B)

@@ -15,12 +15,16 @@ Every op in :mod:`repro.kernels.ops` has up to three lowerings:
   rewards.  Bit-identical results to the kernel path.
 
 ``resolve`` picks the deployed mode: Mosaic on TPU, the XLA lowering
-everywhere else — so ``backend="pallas"`` specs are never slower than
-``backend="reference"`` on any platform, which is what the perf gate's
-``kernelratio_*`` rows (absolute ceiling 1.10) lock in.  The
-``REPRO_KERNEL_MODE`` environment variable forces a mode globally
-(tests use it to pin the interpreter); per-call ``mode=``/legacy
-``interpret=`` arguments override everything.
+everywhere else (the perf gate's ``kernelratio_*`` rows hold its CPU
+cost at or under 1.10x the reference).  The ``REPRO_KERNEL_MODE``
+environment variable forces a mode globally (tests use it to pin the
+interpreter); per-call ``mode=``/legacy ``interpret=`` arguments
+override everything.
+
+Kernel layout: planes are lane-dense ``(rows, 128)`` int32 arrays and
+query tiles ``(1, T)`` lane rows, the shapes the TPU compiler's
+(8, 128) tiling accepts (``plane_blocks``, ``window_spec``,
+``query_tiles``, ``query_spec``).
 """
 
 from __future__ import annotations
@@ -29,6 +33,9 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from . import lanes
 
 MODES = ("mosaic", "interpret", "xla")
 
@@ -78,25 +85,92 @@ def sorted_tile_order(sort_key: jnp.ndarray, tile_t: int) -> jnp.ndarray:
     Pads by duplicating the last (largest) element so sortedness — the
     invariant every window kernel relies on — survives the padding.
     """
-    order = jnp.argsort(sort_key)
+    iota = jnp.arange(sort_key.shape[0], dtype=jnp.int32)
+    # (key, index) pairs are distinct: the stable argsort order, from an
+    # unstable sort that compiles far faster on TPU
+    _, order = jax.lax.sort((sort_key, iota), num_keys=2, is_stable=False)
     pad = (-sort_key.shape[0]) % tile_t
     if pad:
         order = jnp.concatenate([order, jnp.full((pad,), order[-1])])
     return order
 
 
-def plane_blocks(plane: jnp.ndarray, wblk: int) -> jnp.ndarray:
-    """Pad a 1-D plane to ``(nbw, wblk)`` blocks plus one zero block.
+def unpermute(order: jnp.ndarray, n: int, *tiled):
+    """Scatter per-query kernel outputs from tile order back to query
+    order, all in one scatter.  The padding copies of the last query sit
+    in the same (last) tile as the query itself, so every copy carries
+    the same values and it does not matter which one lands."""
+    rows = jnp.stack([x.astype(jnp.int32) for x in tiled], axis=1)
+    out = jnp.zeros((n, len(tiled)), jnp.int32).at[order].set(rows, mode="drop")
+    return tuple(out[:, i] for i in range(len(tiled)))
 
-    The extra block lets clipped window bases (``blk + 1``) stay in
-    range without wrapping into live data.
+
+def plane_blocks(plane: jnp.ndarray, wblk: int) -> jnp.ndarray:
+    """Pad a 1-D plane to whole ``wblk``-slot blocks plus one zero block,
+    laid out lane-dense as ``(nbw * wblk // 128, 128)`` int32.
+
+    A window block is then ``(wblk // 128, 128)`` rows — a tile the TPU
+    compiler accepts when ``wblk`` is a multiple of 1024.  The extra
+    block lets clipped window bases (``blk + 1``) stay in range without
+    wrapping into live data.
     """
+    _rows(wblk)
     total = plane.shape[0]
     nbw = -(-total // wblk) + 1
     pad = nbw * wblk - total
     return jnp.concatenate(
         [plane.astype(jnp.int32), jnp.zeros((pad,), jnp.int32)]
-    ).reshape(nbw, wblk)
+    ).reshape(-1, lanes.LANES)
+
+
+def _rows(wblk: int) -> int:
+    if wblk <= 0 or wblk % lanes.LANES:
+        raise ValueError(f"block of {wblk} slots is not a multiple of {lanes.LANES}")
+    return wblk // lanes.LANES
+
+
+def meta_plane(occ, shf, con) -> jnp.ndarray:
+    """Pack the three QF metadata planes into one int32 plane:
+    bit 0 is_occupied, bit 1 is_shifted, bit 2 is_continuation."""
+    i32 = lambda x: x.astype(jnp.int32)
+    return (i32(occ) & 1) | ((i32(shf) & 1) << 1) | ((i32(con) & 1) << 2)
+
+
+def window_spec(wblk: int, off: int, blk_arg: int = 0):
+    """BlockSpec of window block ``blk + off`` over a ``plane_blocks``
+    plane; ``blk`` is scalar-prefetch argument ``blk_arg``."""
+    return pl.BlockSpec(
+        (_rows(wblk), lanes.LANES), lambda t, *s: (s[blk_arg][t] + off, 0)
+    )
+
+
+def query_tiles(x: jnp.ndarray, tile_t: int) -> jnp.ndarray:
+    """``(B,)`` -> ``(B // tile_t, 1, tile_t)`` int32 query tiles: each
+    grid step reads one ``(1, tile_t)`` lane row, whose block equals the
+    array's last two dims (a tile shape the TPU compiler accepts)."""
+    return x.astype(jnp.int32).reshape(-1, 1, tile_t)
+
+
+def query_spec(tile_t: int):
+    """BlockSpec of query tile ``t`` over a ``query_tiles`` array."""
+    return pl.BlockSpec((1, 1, tile_t), lambda t, *s: (t, 0, 0))
+
+
+# Scalar-prefetch operands live in SMEM (1 MiB on a v5e); a grid whose
+# per-tile scalars would outgrow this many words is split into launches.
+MAX_PREFETCH_WORDS = 1 << 16
+
+
+def launches(n_tiles: int, words_per_tile: int):
+    """``(start, size)`` tile ranges, one per ``pallas_call`` launch, so
+    that no launch prefetches more than ``MAX_PREFETCH_WORDS`` scalars."""
+    step = max(1, MAX_PREFETCH_WORDS // words_per_tile)
+    return [(s, min(step, n_tiles - s)) for s in range(0, n_tiles, step)]
+
+
+def concat_launches(parts):
+    """Join per-launch output lists along the tile axis."""
+    return [jnp.concatenate(p, axis=0) if len(p) > 1 else p[0] for p in zip(*parts)]
 
 
 def window_base(

@@ -10,9 +10,10 @@ scalar-prefetched per tile.  Because the three touched segments are
 the tile — sorted queries turn the probe into a single linear pass over
 the table instead of 3B random gathers.
 
-The gathers themselves are branch-free one-hot contractions (a
-(T x window) iota compare per position), the same trick the QF probe
-kernel uses for its cluster decode.  Queries whose window residency
+The gathers themselves are branch-free one-hot contractions
+(``lanes.gather``: a (T x 128) iota compare per window row), the same
+trick the QF probe kernel uses for its cluster decode, over the same
+lane-dense window and query-row layout.  Queries whose window residency
 fails (tile spans more segments than the window holds) flag overflow
 and the wrapper (ops.py) resolves them on the reference path.
 """
@@ -24,36 +25,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import dispatch, lanes
+
 
 def _fuse_probe_kernel(
-    blk_ref,
-    wbase_ref,
-    tab_a,
-    tab_b,
-    p0_ref,
-    p1_ref,
-    p2_ref,
-    fp_ref,
-    hit_o,
+    blk_ref, wbase_ref, tab_a, tab_b, p0_ref, p1_ref, p2_ref, fp_ref, hit_o
 ):
-    t = pl.program_id(0)
-    T = p0_ref.shape[1]
-    WT = 2 * tab_a.shape[1]
+    base = wbase_ref[pl.program_id(0)]
+    w = jnp.concatenate([tab_a[...], tab_b[...]], axis=0)  # int32 cells
 
-    w = jnp.concatenate([tab_a[0, :], tab_b[0, :]])  # (WT,) int32 cells
+    def cell(p_ref):  # w[p - base] for the tile's (T, 1) position column
+        return lanes.gather(w, lanes.row_to_col(p_ref[0]) - base)
 
-    base = wbase_ref[t]
-    r0 = p0_ref[0, :] - base  # (T,) window-relative positions
-    r1 = p1_ref[0, :] - base
-    r2 = p2_ref[0, :] - base
-
-    js = jax.lax.broadcasted_iota(jnp.int32, (T, WT), 1)
-
-    def gather(rel):  # one-hot contraction: w[rel] without dynamic indexing
-        return jnp.sum(jnp.where(js == rel[:, None], w[None, :], 0), axis=1)
-
-    got = gather(r0) ^ gather(r1) ^ gather(r2)
-    hit_o[0, :] = (got == fp_ref[0, :]).astype(jnp.int32)
+    got = cell(p0_ref) ^ cell(p1_ref) ^ cell(p2_ref)
+    hit = got == lanes.row_to_col(fp_ref[0])
+    hit_o[0] = lanes.col_to_row(hit.astype(jnp.int32))
 
 
 def fuse_probe_tiles(
@@ -80,38 +66,42 @@ def fuse_probe_tiles(
     assert B % tile_t == 0
     n_tiles = B // tile_t
 
-    nbw = -(-total // wblk) + 1  # plus one zero block for clipped windows
-    tpad = nbw * wblk
-    tab2 = jnp.concatenate(
-        [table.astype(jnp.int32), jnp.zeros((tpad - total,), jnp.int32)]
-    ).reshape(nbw, wblk)
-
-    p0 = p0_sorted.reshape(n_tiles, tile_t)
-    p1 = p1_sorted.reshape(n_tiles, tile_t)
-    p2 = p2_sorted.reshape(n_tiles, tile_t)
-    fp2 = fp_sorted.astype(jnp.int32).reshape(n_tiles, tile_t)
-
-    blk = jnp.clip(p0[:, 0] // wblk, 0, nbw - 2).astype(jnp.int32)
-    wbase = blk * wblk
-    # all three positions of every query must land inside [wbase, wbase+2*wblk)
-    reach = jnp.maximum(jnp.max(p1, axis=1), jnp.max(p2, axis=1))
-    tile_fits = (reach - wbase) < (2 * wblk)
-
-    win = lambda off: pl.BlockSpec((1, wblk), lambda t, blk, wbase: (blk[t] + off, 0))
-    qspec = pl.BlockSpec((1, tile_t), lambda t, blk, wbase: (t, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_tiles,),
-        in_specs=[win(0), win(1)] + [qspec] * 4,
-        out_specs=[qspec],
+    tab2 = dispatch.plane_blocks(table, wblk)
+    p0, p1, p2, fp3 = (
+        dispatch.query_tiles(x, tile_t)
+        for x in (p0_sorted, p1_sorted, p2_sorted, fp_sorted)
     )
-    (hit2,) = pl.pallas_call(
-        _fuse_probe_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_tiles, tile_t), jnp.int32)],
-        interpret=interpret,
-    )(blk, wbase, tab2, tab2, p0, p1, p2, fp2)
 
-    ovf2 = jnp.broadcast_to((~tile_fits[:, None]).astype(jnp.int32), hit2.shape)
-    return hit2.reshape(B), ovf2.reshape(B)
+    # all three positions of every query must land inside [wbase, wbase+2*wblk)
+    reach = jnp.maximum(jnp.max(p1, axis=(1, 2)), jnp.max(p2, axis=(1, 2)))
+    blk, wbase, tile_fits = dispatch.window_base(p0[:, 0, 0], reach, total, wblk)
+
+    qspec = dispatch.query_spec(tile_t)
+
+    def launch(s, n):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n,),
+            in_specs=[dispatch.window_spec(wblk, 0), dispatch.window_spec(wblk, 1)]
+            + [qspec] * 4,
+            out_specs=[qspec],
+        )
+        return pl.pallas_call(
+            _fuse_probe_kernel,
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)],
+            interpret=interpret,
+        )(
+            blk[s : s + n],
+            wbase[s : s + n],
+            tab2,
+            tab2,
+            *(x[s : s + n] for x in (p0, p1, p2, fp3)),
+        )
+
+    (hit3,) = dispatch.concat_launches(
+        launch(s, n) for s, n in dispatch.launches(n_tiles, 2)
+    )
+
+    ovf3 = jnp.broadcast_to((~tile_fits[:, None, None]).astype(jnp.int32), hit3.shape)
+    return hit3.reshape(B), ovf3.reshape(B)

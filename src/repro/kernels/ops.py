@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.core import fuse_filter as ffc
 from repro.core import quotient_filter as qf
+from repro.core import scan
 from . import dispatch
 from .bloom_block import bloom_count_tiles, bloom_probe_tiles
 from .cascade_probe import cascade_probe_tiles
@@ -52,7 +53,7 @@ def _build_sorted(cfg, fq, fr, n, *, mode, block_s):
 
         # sentinel stays out of the subtraction (-INT32_MAX - idx wraps
         # for idx >= 2)
-        pos = idx + jax.lax.cummax(jnp.where(valid, fq - idx, -INT32_MAX))
+        pos = idx + scan.cummax(jnp.where(valid, fq - idx, -INT32_MAX))
         overflow = jnp.any(valid & (pos >= t))
         spos = jnp.where(valid, pos, INT32_MAX)
         con_b = valid & (idx > 0) & (fq == jnp.roll(fq, 1))
@@ -70,7 +71,7 @@ def _build_sorted(cfg, fq, fr, n, *, mode, block_s):
         occ = (
             jnp.zeros((t,), jnp.bool_)
             .at[jnp.where(valid, fq, INT32_MAX)]
-            .set(True, mode="drop")
+            .set(True, mode="drop", indices_are_sorted=True)
         )
         return qf.QFState(
             rem=rem_i32.astype(jnp.uint32),
@@ -93,7 +94,7 @@ def build_sorted(
     *,
     mode: str | None = None,
     interpret: bool | None = None,
-    block_s: int = 256,
+    block_s: int = 1024,
 ) -> qf.QFState:
     """Mode-dispatched equivalent of ``quotient_filter.build_sorted``.
 
@@ -139,10 +140,7 @@ def _lookup(cfg, state, fq, fr, *, mode, tile_t, wblk):
         wblk=wblk,
         interpret=dispatch.pallas_interpret(mode),
     )
-    # un-permute (padding wrote duplicates of a real slot; last write wins
-    # with identical values, so it is harmless)
-    present = jnp.zeros((B0,), jnp.int32).at[osort].set(present_s, mode="drop")
-    ovf = jnp.zeros((B0,), jnp.int32).at[osort].max(ovf_s, mode="drop")
+    present, ovf = dispatch.unpermute(osort, B0, present_s, ovf_s)
 
     def resolve(args):
         present, ovf = args
@@ -211,8 +209,7 @@ def _fuse_lookup(cfg, state, fq, fr, *, mode, tile_t, wblk):
         wblk=wblk,
         interpret=dispatch.pallas_interpret(mode),
     )
-    hit = jnp.zeros((B0,), jnp.int32).at[osort].set(hit_s, mode="drop")
-    ovf = jnp.zeros((B0,), jnp.int32).at[osort].max(ovf_s, mode="drop")
+    hit, ovf = dispatch.unpermute(osort, B0, hit_s, ovf_s)
 
     def resolve(args):
         hit, ovf = args
@@ -323,7 +320,7 @@ def _cascade_lookup(
         # level's quotient
         B0 = keys.shape[0]
         iota = jnp.arange(B0, dtype=jnp.int32)
-        _, _, perm = jax.lax.sort((fqc, frc, iota), num_keys=2)
+        _, _, perm = jax.lax.sort((fqc, frc, iota), num_keys=3, is_stable=False)
         pad = (-B0) % tile_t
         osort = (
             jnp.concatenate([perm, jnp.full((pad,), perm[-1])]) if pad else perm
@@ -352,8 +349,7 @@ def _cascade_lookup(
             wblk=wblk,
             interpret=dispatch.pallas_interpret(mode),
         )
-        hitm = jnp.zeros((B0,), jnp.int32).at[osort].set(hitm_s, mode="drop")
-        ovfm = jnp.zeros((B0,), jnp.int32).at[osort].max(ovfm_s, mode="drop")
+        hitm, ovfm = dispatch.unpermute(osort, B0, hitm_s, ovfm_s)
 
         for lvl, (c, s) in enumerate(zip(qf_cfgs, qf_states)):
             hit_l = ((hitm >> lvl) & 1) > 0
@@ -430,7 +426,7 @@ def _span_math(cfg, fq, fr, k, last_pos, last_fq):
     valid = idx < kk
 
     d = jnp.where(valid, fq - idx, -INT32_MAX)
-    pos = idx + jnp.maximum(last_pos + 1, jax.lax.cummax(d))
+    pos = idx + jnp.maximum(last_pos + 1, scan.cummax(d))
     overflow = jnp.any(valid & (pos >= t))
     spos = jnp.where(valid, pos, INT32_MAX)
 
@@ -451,7 +447,9 @@ def _build_span(cfg, state, fq, fr, k, last_pos, last_fq, *, mode, block_s):
     kk, valid, spos, con_bits, shf_bits, overflow, nlp, nlf = _span_math(
         cfg, fq, fr, k, last_pos, last_fq
     )
-    occ = state.occ.at[jnp.where(valid, fq, INT32_MAX)].set(True, mode="drop")
+    occ = state.occ.at[jnp.where(valid, fq, INT32_MAX)].set(
+        True, mode="drop", indices_are_sorted=True
+    )
 
     if dispatch.is_pallas(mode):
         # kernel-resident append: positions strictly increase past every
@@ -477,10 +475,10 @@ def _build_span(cfg, state, fq, fr, k, last_pos, last_fq, *, mode, block_s):
         )
     else:
         new = qf.QFState(
-            rem=state.rem.at[spos].set(fr, mode="drop"),
+            rem=state.rem.at[spos].set(fr, mode="drop", indices_are_sorted=True),
             occ=occ,
-            shf=state.shf.at[spos].set(shf_bits, mode="drop"),
-            con=state.con.at[spos].set(con_bits, mode="drop"),
+            shf=state.shf.at[spos].set(shf_bits, mode="drop", indices_are_sorted=True),
+            con=state.con.at[spos].set(con_bits, mode="drop", indices_are_sorted=True),
             n=state.n + kk,
             overflow=state.overflow | overflow,
         )
@@ -498,7 +496,7 @@ def build_span(
     *,
     mode: str | None = None,
     interpret: bool | None = None,
-    block_s: int = 256,
+    block_s: int = 1024,
 ):
     """Append a bounded sorted span (first ``k`` rows valid) to a
     partially built QF in one pass — the multi-chunk form of
@@ -617,7 +615,7 @@ def bloom_counts(
     *,
     mode: str | None = None,
     interpret: bool | None = None,
-    block_s: int = 512,
+    block_s: int = 1024,
 ):
     """Aggregate a flat batch of cell indices into an int32 counts plane.
 
@@ -651,8 +649,7 @@ def _bloom_probe(cells, idx, *, mode, tile_t, wblk):
         wblk=wblk,
         interpret=dispatch.pallas_interpret(mode),
     )
-    hit = jnp.zeros((B0,), jnp.int32).at[osort].set(hit_s, mode="drop")
-    ovf = jnp.zeros((B0,), jnp.int32).at[osort].max(ovf_s, mode="drop")
+    hit, ovf = dispatch.unpermute(osort, B0, hit_s, ovf_s)
 
     def resolve(args):
         hit, ovf = args

@@ -9,9 +9,12 @@ grid = one program per S-slot output tile.  Because probe positions are
 strictly increasing, the items landing in an S-slot tile are a
 contiguous range of at most S items, whose location is scalar-prefetched
 (`blk[t]` = item-block index).  Each program loads two consecutive
-S-item blocks (covering any alignment), builds an (2S x S) match matrix
-``pos - tile_base == lane`` and reduces it onto the tile — pure VPU
-work, no data-dependent control flow, VMEM-resident.
+S-item blocks (covering any alignment) and, for every 128-item row and
+every 128-slot output row, reduces a (128 x 128) match matrix
+``pos - row_base == lane`` onto the output row — pure VPU work, no
+data-dependent control flow, VMEM-resident.  Items and slots are
+lane-dense ``(S // 128, 128)`` blocks; S is a multiple of 1024 for the
+TPU compiler's (8, 128) tiling.
 
 The is_occupied plane is a trivial one-line scatter handled by the
 wrapper (ops.py); this kernel emits the payload planes (remainder +
@@ -25,22 +28,73 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import dispatch, lanes
 
-def _build_kernel(blk_ref, pos_a, pos_b, fr_a, fr_b, mb_a, mb_b, rem_o, meta_o):
-    t = pl.program_id(0)
-    S = rem_o.shape[1]
-    base = t * S
 
-    w_pos = jnp.concatenate([pos_a[0, :], pos_b[0, :]])  # (2S,)
-    w_fr = jnp.concatenate([fr_a[0, :], fr_b[0, :]])
-    w_mb = jnp.concatenate([mb_a[0, :], mb_b[0, :]])
+def scatter_rows(pos, vals, out_refs, base):
+    """Reduce items onto lane-dense output rows: ``out[p - base] = v``.
 
-    rel = w_pos - base  # (2S,) ; outside [0, S) contributes nothing
-    cols = jax.lax.broadcasted_iota(jnp.int32, (2 * S, S), 1)
-    hit = rel[:, None] == cols  # (2S, S) one-hot by construction
+    ``pos`` is an ``(rows, 128)`` int32 item block, ``vals`` a list of
+    item blocks of the same shape, ``out_refs`` one ``(sr, 128)`` output
+    ref per value.  Items whose position falls outside the ``sr * 128``
+    output slots contribute nothing; several items on one slot add up
+    (callers with unique positions get a plain scatter).
+    """
+    sr, width = out_refs[0].shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (width, width), 1)
+    acc = [[jnp.zeros((width, width), jnp.int32)] * sr for _ in out_refs]
+    for k in range(pos.shape[0]):
+        p = lanes.row_to_col(pos[k : k + 1, :]) - base
+        cols = [lanes.row_to_col(v[k : k + 1, :]) for v in vals]
+        for c in range(sr):
+            hit = (p - c * width) == lane
+            for a, col in zip(acc, cols):
+                a[c] = a[c] + jnp.where(hit, col, 0)
+    for ref, a in zip(out_refs, acc):
+        for c in range(sr):
+            ref[c : c + 1, :] = jnp.sum(a[c], axis=0, keepdims=True)
 
-    rem_o[0, :] = jnp.sum(jnp.where(hit, w_fr[:, None], 0), axis=0)
-    meta_o[0, :] = jnp.sum(jnp.where(hit, w_mb[:, None], 0), axis=0)
+
+def tile_base(first_ref, out_ref):
+    """Absolute first slot of this program's output tile: the launch's
+    first tile (scalar ``first_ref[0]``) plus the grid position."""
+    return (first_ref[0] + pl.program_id(0)) * out_ref.shape[0] * out_ref.shape[1]
+
+
+def _build_kernel(
+    blk_ref, first_ref, pos_a, pos_b, fr_a, fr_b, mb_a, mb_b, rem_o, meta_o
+):
+    base = tile_base(first_ref, rem_o)
+    cat = lambda a, b: jnp.concatenate([a[...], b[...]], axis=0)
+    scatter_rows(
+        cat(pos_a, pos_b), [cat(fr_a, fr_b), cat(mb_a, mb_b)], [rem_o, meta_o], base
+    )
+
+
+def launch_tiles(kernel, blk, items, S, n_out, interpret, s, n):
+    """One launch of a scatter grid over output tiles ``[s, s + n)``.
+
+    ``items`` are ``(rows, 128)`` item planes, each passed as the two
+    consecutive ``S``-item blocks from ``blk[t]``; the kernel writes
+    ``n_out`` lane-dense ``(S // 128, 128)`` int32 output tiles.  Its
+    scalar refs are ``blk`` and the launch's first tile ``s``.
+    """
+    win = lambda off: dispatch.window_spec(S, off)
+    out = pl.BlockSpec((S // lanes.LANES, lanes.LANES), lambda t, *_: (t, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n,),
+        in_specs=[win(off) for _ in items for off in (0, 1)],
+        out_specs=[out] * n_out,
+    )
+    plane = jax.ShapeDtypeStruct((n * S // lanes.LANES, lanes.LANES), jnp.int32)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=[plane] * n_out, interpret=interpret
+    )(
+        blk[s : s + n],
+        jnp.full((1,), s, jnp.int32),
+        *(x for x in items for _ in (0, 1)),
+    )
 
 
 def qf_build_planes(
@@ -49,13 +103,14 @@ def qf_build_planes(
     meta_bits: jnp.ndarray,
     total_slots: int,
     *,
-    block_s: int = 256,
+    block_s: int = 1024,
     interpret: bool = True,
 ):
     """Scatter items (pos strictly increasing, INT32_MAX padding) into
     (rem, meta) planes of length total_slots.
 
     meta_bits packs is_continuation | is_shifted << 1 per item.
+    ``block_s`` is a multiple of 128.
     """
     S = block_s
     n_tiles = -(-total_slots // S)
@@ -66,34 +121,21 @@ def qf_build_planes(
     n_blocks = -(-n // S) + 1
     pad = n_blocks * S - n
     pos_p = jnp.concatenate([pos, jnp.full((pad,), jnp.int32(2**31 - 1))])
-    fr_p = jnp.concatenate([fr.astype(jnp.int32), jnp.zeros((pad,), jnp.int32)])
-    mb_p = jnp.concatenate([meta_bits.astype(jnp.int32), jnp.zeros((pad,), jnp.int32)])
-    pos2 = pos_p.reshape(n_blocks, S)
-    fr2 = fr_p.reshape(n_blocks, S)
-    mb2 = mb_p.reshape(n_blocks, S)
+    rows = lambda x: jnp.concatenate(
+        [x.astype(jnp.int32), jnp.zeros((pad,), jnp.int32)]
+    ).reshape(-1, lanes.LANES)
+    pos2 = pos_p.reshape(-1, lanes.LANES)
+    fr2 = rows(fr)
+    mb2 = rows(meta_bits)
 
     # scalar prefetch: first item-block feeding each output tile
     starts = jnp.searchsorted(pos_p, jnp.arange(n_tiles, dtype=jnp.int32) * S)
     blk = jnp.minimum(starts // S, n_blocks - 2).astype(jnp.int32)
 
-    win = lambda off: pl.BlockSpec((1, S), lambda t, blk: (blk[t] + off, 0))
-    out = pl.BlockSpec((1, S), lambda t, blk: (t, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_tiles,),
-        in_specs=[win(0), win(1), win(0), win(1), win(0), win(1)],
-        out_specs=[out, out],
+    rem2, meta2 = dispatch.concat_launches(
+        launch_tiles(_build_kernel, blk, [pos2, fr2, mb2], S, 2, interpret, s, n)
+        for s, n in dispatch.launches(n_tiles, 1)
     )
-    rem2, meta2 = pl.pallas_call(
-        _build_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, S), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, S), jnp.int32),
-        ],
-        interpret=interpret,
-    )(blk, pos2, pos2, fr2, fr2, mb2, mb2)
     rem = rem2.reshape(t_pad)[:total_slots]
     meta = meta2.reshape(t_pad)[:total_slots]
     return rem, meta

@@ -8,11 +8,18 @@ whose aligned start is scalar-prefetched per tile.  Sorted queries make
 neighbouring windows coalesce, so HBM traffic is a linear stream over
 the touched region instead of random gathers.
 
-In-window cluster decode is branch-free rank/select arithmetic over a
-(T x 2*WBLK) broadcast: anchor = last unshifted slot left of the
-quotient, R = occupied count to the bucket, run = R-th run-start after
-the anchor (via a shared cumsum), then a remainder compare — the
-vectorized form of the paper's Fig. 3 walk.
+In-window cluster decode is branch-free rank/select arithmetic (the
+vectorized form of the paper's Fig. 3 walk).  Two prefix counts are
+shared over the tile: O (occupied buckets) and S (run starts).  At any
+unshifted slot b the runs started before b equal the occupied buckets
+before b, so ``S - O`` just before an unshifted slot is one window
+constant K: the query's run is the one with ordinal ``O[fq] + K``, and
+the query hits if a slot of that run holds its remainder.
+
+Layout: the window is two ``(wblk // 128, 128)`` lane-dense blocks, the
+metadata bits are packed into one int32 plane (``dispatch.meta_plane``)
+and each query tile is one ``(1, T)`` lane row, moved to a ``(T, 1)``
+column so queries broadcast against window lanes (``lanes``).
 
 Queries whose tile span or cluster exceeds the window raise a per-query
 overflow flag; the wrapper (ops.py) resolves those on the exact path.
@@ -25,83 +32,76 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import dispatch, lanes
 
-def window_decode(w_rem, w_occ, w_shf, w_con, fq, fr, base):
+
+def window_decode(rem, meta, fq, fr, base):
     """Branch-free cluster decode of one query tile against one window.
 
-    ``w_*`` are the (WT,) window planes (rem int32, rest bool), ``fq`` /
-    ``fr`` the (T,) tile queries, ``base`` the window's absolute start
-    slot.  Returns ``(present, ovf)`` bool (T,) — the vectorized paper
-    Fig. 3 walk shared by the single-level and fused-cascade kernels.
+    ``rem`` / ``meta`` are the ``(rows, 128)`` int32 window planes
+    (``meta`` packed by ``dispatch.meta_plane``), ``fq`` / ``fr`` the
+    ``(T, 1)`` query columns, ``base`` the window's absolute start slot.
+    Returns ``(present, ovf)`` bool ``(T, 1)`` — shared by the
+    single-level and fused-cascade kernels.
     """
-    T = fq.shape[0]
-    WT = w_rem.shape[0]
-    nonempty = w_occ | w_shf
+    rows, width = rem.shape
+    occ = meta & 1
+    shf = (meta >> 1) & 1
+    con = (meta >> 2) & 1
+    nonempty = (occ | shf) > 0
+    start = jnp.where(nonempty & (con == 0), 1, 0)
 
-    # shared over the tile: run-start prefix counts
-    run_start = (nonempty & ~w_con).astype(jnp.int32)
-    cum = jnp.cumsum(run_start.reshape(1, WT), axis=1)[0]  # (WT,)
+    run = lanes.prefix_sum(start)  # run ordinal of every slot
+    occ_cum = lanes.prefix_sum(occ)
+    unshifted = shf == 0
+    slot = lanes.flat_index(rem.shape)
 
-    rel = fq - base  # (T,) in [0, WT) when tile fits
+    def reduce(f, x):
+        return f(f(x, axis=1, keepdims=True), axis=0, keepdims=True)
 
-    js = jax.lax.broadcasted_iota(jnp.int32, (T, WT), 1)
-    relc = rel[:, None]
-
-    at_q = js == relc
-    occ_q = jnp.any(at_q & w_occ[None, :], axis=1)
-
-    # anchor: largest j <= rel with !is_shifted
-    m1 = (~w_shf)[None, :] & (js <= relc)
-    b = jnp.max(jnp.where(m1, js, -1), axis=1)  # (T,)
-    ovf_left = b < 0
-
-    # R = #occupied buckets in [b, fq]
-    R = jnp.sum(
-        (w_occ[None, :] & (js >= b[:, None]) & (js <= relc)).astype(jnp.int32),
-        axis=1,
+    # first unshifted slot, and the window constant K = S - O just
+    # before any unshifted slot (-1 when the window has none: then
+    # every query overflows left)
+    first_anchor = reduce(jnp.min, jnp.where(unshifted, slot, rows * width))
+    k = reduce(
+        jnp.max, jnp.where(unshifted, (run - start) - (occ_cum - occ), -1)
     )
-    cum_before = jnp.sum(
-        jnp.where(js == (b - 1)[:, None], cum[None, :], 0), axis=1
-    )  # 0 when b == 0
-    C = cum_before + R
+    runs = jnp.where(nonempty, run, -1)
+    last_run = runs[rows - 1 :, width - 1 :]  # (1, 1)
+    run_total = run[rows - 1 :, width - 1 :]
 
-    in_run = (cum[None, :] == C[:, None]) & nonempty[None, :]
-    present = occ_q & jnp.any(in_run & (w_rem[None, :] == fr[:, None]), axis=1)
+    rel = fq - base  # (T, 1) in [0, rows * 128) when the tile fits
+    g = lanes.gather(occ_cum * 2 + occ, rel)
+    occ_q = (g & 1) > 0
+    want = (g >> 1) + k  # ordinal of the query's run
 
-    ovf_right = in_run[:, -1]
-    ovf_nostart = occ_q & ~ovf_left & (cum[-1] < C)
+    hit = jnp.zeros((fq.shape[0], width), jnp.bool_)
+    for i in range(rows):
+        hit = hit | ((runs[i : i + 1, :] == want) & (rem[i : i + 1, :] == fr))
+    present = occ_q & jnp.any(hit, axis=1, keepdims=True)
+
+    ovf_left = rel < first_anchor
+    ovf_right = last_run == want  # the run may continue past the window
+    ovf_nostart = ~ovf_left & (run_total < want)  # run starts past it
     ovf = occ_q & (ovf_left | ovf_right | ovf_nostart)
     return present, ovf
 
 
 def _probe_kernel(
-    blk_ref,
-    wbase_ref,
-    rem_a,
-    rem_b,
-    occ_a,
-    occ_b,
-    shf_a,
-    shf_b,
-    con_a,
-    con_b,
-    fq_ref,
-    fr_ref,
-    present_o,
-    ovf_o,
+    blk_ref, wbase_ref, rem_a, rem_b, meta_a, meta_b, fq_ref, fr_ref, present_o, ovf_o
 ):
     t = pl.program_id(0)
-
-    w_rem = jnp.concatenate([rem_a[0, :], rem_b[0, :]])  # (WT,)
-    w_occ = jnp.concatenate([occ_a[0, :], occ_b[0, :]]) > 0
-    w_shf = jnp.concatenate([shf_a[0, :], shf_b[0, :]]) > 0
-    w_con = jnp.concatenate([con_a[0, :], con_b[0, :]]) > 0
-
+    rem = jnp.concatenate([rem_a[...], rem_b[...]], axis=0)
+    meta = jnp.concatenate([meta_a[...], meta_b[...]], axis=0)
     present, ovf = window_decode(
-        w_rem, w_occ, w_shf, w_con, fq_ref[0, :], fr_ref[0, :], wbase_ref[t]
+        rem,
+        meta,
+        lanes.row_to_col(fq_ref[0]),
+        lanes.row_to_col(fr_ref[0]),
+        wbase_ref[t],
     )
-    present_o[0, :] = present.astype(jnp.int32)
-    ovf_o[0, :] = ovf.astype(jnp.int32)
+    present_o[0] = lanes.col_to_row(present.astype(jnp.int32))
+    ovf_o[0] = lanes.col_to_row(ovf.astype(jnp.int32))
 
 
 def qf_probe_tiles(
@@ -118,52 +118,57 @@ def qf_probe_tiles(
 ):
     """Probe sorted queries. Returns (present, overflow) int32 (B,).
 
-    Planes are int32; fq_sorted must be ascending, padded to a multiple
-    of tile_t (duplicate-last padding preserves sortedness).  Tiles
-    whose quotient span exceeds the window report overflow for all
-    their queries (handled by the caller's exact path).
+    Planes are any integer/bool dtype; fq_sorted must be ascending,
+    padded to a multiple of tile_t (duplicate-last padding preserves
+    sortedness).  ``wblk`` is a multiple of 128 (of 1024 for the TPU
+    compiler's tiling).  Tiles whose quotient span exceeds the window
+    report overflow for all their queries (handled by the caller's
+    exact path).
     """
     total = rem.shape[0]
     B = fq_sorted.shape[0]
     assert B % tile_t == 0
     n_tiles = B // tile_t
 
-    nbw = -(-total // wblk) + 1  # plus one zero (empty-slot) block
-    tpad = nbw * wblk
+    rem2 = dispatch.plane_blocks(rem, wblk)
+    meta2 = dispatch.plane_blocks(dispatch.meta_plane(occ, shf, con), wblk)
+    fq3 = dispatch.query_tiles(fq_sorted, tile_t)
+    fr3 = dispatch.query_tiles(fr_sorted, tile_t)
 
-    def pad_plane(x):
-        return jnp.concatenate(
-            [x.astype(jnp.int32), jnp.zeros((tpad - total,), jnp.int32)]
-        ).reshape(nbw, wblk)
-
-    rem2, occ2, shf2, con2 = map(pad_plane, (rem, occ, shf, con))
-    fq2 = fq_sorted.reshape(n_tiles, tile_t)
-    fr2 = fr_sorted.astype(jnp.int32).reshape(n_tiles, tile_t)
-
-    min_fq = fq2[:, 0]
-    max_fq = fq2[:, -1]
-    blk = jnp.clip((min_fq - wblk // 4) // wblk, 0, nbw - 2).astype(jnp.int32)
-    wbase = blk * wblk
-    tile_fits = (max_fq - wbase) < (2 * wblk - wblk // 4)  # room for run tail
-
-    win = lambda off: pl.BlockSpec((1, wblk), lambda t, blk, wbase: (blk[t] + off, 0))
-    qspec = pl.BlockSpec((1, tile_t), lambda t, blk, wbase: (t, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_tiles,),
-        in_specs=[win(0), win(1)] * 4 + [qspec, qspec],
-        out_specs=[qspec, qspec],
+    # room for the run tail past the last query
+    blk, wbase, tile_fits = dispatch.window_base(
+        fq3[:, 0, 0], fq3[:, 0, -1], total, wblk, margin=wblk // 4
     )
-    present2, ovf2 = pl.pallas_call(
-        _probe_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, tile_t), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, tile_t), jnp.int32),
-        ],
-        interpret=interpret,
-    )(blk, wbase, rem2, rem2, occ2, occ2, shf2, shf2, con2, con2, fq2, fr2)
 
-    ovf2 = ovf2 | (~tile_fits[:, None]).astype(jnp.int32)
-    return present2.reshape(B), ovf2.reshape(B)
+    win = lambda off: dispatch.window_spec(wblk, off)
+    qspec = dispatch.query_spec(tile_t)
+
+    def launch(s, n):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n,),
+            in_specs=[win(0), win(1), win(0), win(1), qspec, qspec],
+            out_specs=[qspec, qspec],
+        )
+        return pl.pallas_call(
+            _probe_kernel,
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)] * 2,
+            interpret=interpret,
+        )(
+            blk[s : s + n],
+            wbase[s : s + n],
+            rem2,
+            rem2,
+            meta2,
+            meta2,
+            fq3[s : s + n],
+            fr3[s : s + n],
+        )
+
+    present3, ovf3 = dispatch.concat_launches(
+        launch(s, n) for s, n in dispatch.launches(n_tiles, 2)
+    )
+
+    ovf3 = ovf3 | (~tile_fits[:, None, None]).astype(jnp.int32)
+    return present3.reshape(B), ovf3.reshape(B)

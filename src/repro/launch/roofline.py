@@ -154,10 +154,7 @@ def kernel_roofline(fn, *args, **kw) -> "Roofline":
     import jax
 
     compiled = jax.jit(fn).lower(*args, **kw).compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
-    cost = cost or {}
+    cost = compiled.cost_analysis() or {}
     return Roofline(
         flops=float(cost.get("flops", 0.0) or 0.0),
         bytes_accessed=float(cost.get("bytes accessed", 0.0) or 0.0),

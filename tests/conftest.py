@@ -30,8 +30,7 @@ def _clear_jax_caches_per_module():
     keeps every module's compile behavior independent of suite order.
     """
     yield
-    if hasattr(jax, "clear_caches"):  # jax >= 0.4.9; no-op guard below
-        jax.clear_caches()
+    jax.clear_caches()
 
 if settings is not None:
     settings.register_profile("ci", derandomize=True, deadline=None)

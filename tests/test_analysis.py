@@ -11,7 +11,8 @@ Three layers:
   mismatches are rejected; the real kernels validate clean.
 
 Plus the regression test for the bug the trace audit surfaced: the
-xor_fuse reference lookup ran fully eager (pjit=0) before PR 8's fix.
+xor_fuse reference lookup ran fully eager (no jit primitive) before it
+was jitted.
 """
 
 from __future__ import annotations
@@ -21,12 +22,13 @@ import sys
 
 import pytest
 
-from repro.analysis import toml_lite, trace_audit
+from repro.analysis import trace_audit
 from repro.analysis.lint import (
     BaselineEntry,
     analyze_sources,
     apply_baseline,
     load_baseline,
+    load_toml,
 )
 from repro.analysis.spec_check import CapturedCall, validate_call
 
@@ -222,13 +224,14 @@ class TestBaseline:
 
 
 # ---------------------------------------------------------------------------
-# toml_lite fallback parser
+# TOML loading (stdlib tomllib behind lint.load_toml)
 # ---------------------------------------------------------------------------
 
 
 class TestTomlLite:
-    def test_sections_arrays_and_types(self):
-        data = toml_lite.loads(
+    def test_sections_arrays_and_types(self, tmp_path):
+        path = tmp_path / "demo.toml"
+        path.write_text(
             "[tool.demo]\n"
             'name = "x"  # comment\n'
             "n = 3\n"
@@ -240,14 +243,16 @@ class TestTomlLite:
             "[[tool.demo.allow]]\n"
             'rule = "R2"\n'
         )
-        sec = data["tool"]["demo"]
+        sec = load_toml(str(path))["tool"]["demo"]
         assert sec["name"] == "x" and sec["n"] == 3 and sec["ratio"] == 1.5
         assert sec["on"] is True and sec["paths"] == ["a", "b"]
         assert [e["rule"] for e in sec["allow"]] == ["R1", "R2"]
 
-    def test_malformed_raises(self):
+    def test_malformed_raises(self, tmp_path):
+        path = tmp_path / "bad.toml"
+        path.write_text("this is not toml\n")
         with pytest.raises(ValueError):
-            toml_lite.loads("this is not toml\n")
+            load_toml(str(path))
 
 
 # ---------------------------------------------------------------------------
@@ -259,7 +264,7 @@ def _fam(status="traced", eqns=100, prims=None):
     e = {"status": status}
     if status == "traced":
         e["eqns"] = eqns
-        e["prims"] = prims or {"add": 3, "pjit": 1}
+        e["prims"] = prims or {"add": 3, "jit": 1}
     return e
 
 
@@ -325,7 +330,7 @@ class TestTraceAudit:
 class TestFuseLookupCompiled:
     def test_xor_fuse_contains_traces_compiled(self):
         """Regression: the reference binary-fuse lookup silently ran
-        fully eager (pjit=0 in its jaxpr) until it was jitted with the
+        fully eager (no jit primitive in its jaxpr) until it was jitted with the
         config static — the exact bug class the trace audit exists to
         catch."""
         import jax
@@ -337,7 +342,7 @@ class TestFuseLookupCompiled:
             state, trace_audit._keys(16)
         )
         _, prims = trace_audit._count_jaxpr(jaxpr)
-        assert prims.get("pjit", 0) >= 1
+        assert prims.get("jit", 0) >= 1
 
 
 # ---------------------------------------------------------------------------

@@ -540,3 +540,27 @@ class TestOracleParity:
         ok = np.asarray(ovf) == 0
         assert ok.any()
         np.testing.assert_array_equal(np.asarray(hit, bool)[ok], np.asarray(want)[ok])
+
+
+def test_grids_split_into_launches_match_one_launch(monkeypatch):
+    """A grid whose scalar prefetch would outgrow SMEM is split into
+    several launches; the split outputs must equal the single launch."""
+    cfg, st, keys, _ = _mkfilter(12, 10, 2000)
+    fq, fr = qf.fingerprints(cfg, keys)
+    fq_s, fr_s = qf._pad_sort(fq, fr, jnp.ones(fq.shape, bool))
+    idx = jnp.arange(fq_s.shape[0], dtype=jnp.int32)
+    spos = idx + jax.lax.cummax(fq_s - idx)
+    planes = tuple(x.astype(jnp.int32) for x in (st.rem, st.occ, st.shf, st.con))
+    order = jnp.argsort(fq)
+    q, r = fq[order][:1920], fr[order][:1920].astype(jnp.int32)
+
+    def run():
+        probe = qf_probe_tiles(*planes, q, r, tile_t=128, wblk=1024)
+        build = qf_build_planes(spos, fr_s, idx & 3, cfg.total_slots, block_s=128)
+        return [np.asarray(x) for x in probe + build]
+
+    whole = run()
+    monkeypatch.setattr(dispatch, "MAX_PREFETCH_WORDS", 8)
+    assert len(dispatch.launches(15, 2)) > 1
+    for got, want in zip(run(), whole):
+        np.testing.assert_array_equal(got, want)
