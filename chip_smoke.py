@@ -90,19 +90,17 @@ def fingerprints(keys: np.ndarray, p: int, seed: int = 0) -> np.ndarray:
 def member(stored: np.ndarray, queries: np.ndarray) -> np.ndarray:
     """Sorted-array membership of each query among ``stored``.
 
-    The (small) query batch is sorted and every stored value is looked
-    up in it, so the large array is never sorted."""
-    order = np.argsort(queries, kind="stable")
+    The queries are searched in sorted order, which keeps the binary
+    searches of a large stored array cache-friendly."""
+    s = np.sort(stored)
+    order = np.argsort(queries)
     sq = queries[order]
-    pos = np.searchsorted(sq, stored)
-    hit = pos < sq.size
-    hit[hit] = sq[pos[hit]] == stored[hit]
+    pos = np.searchsorted(s, sq)
     found = np.zeros(sq.size, bool)
-    found[pos[hit]] = True
-    # equal queries share the first position: spread each hit over its run
-    first = np.searchsorted(sq, sq)
+    inside = pos < s.size
+    found[inside] = s[pos[inside]] == sq[inside]
     out = np.empty(sq.size, bool)
-    out[order] = found[first]
+    out[order] = found
     return out
 
 
