@@ -185,14 +185,9 @@ def _compact(keep: jnp.ndarray, values: jnp.ndarray, fill) -> jnp.ndarray:
     return out[1::2]
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def extract(cfg: QFConfig, state: QFState):
-    """Decode the filter back to sorted fingerprints.
-
-    Returns (fq, fr, n): padded (total_slots,) arrays whose first n
-    entries are the sorted fingerprint multiset (padding = sentinels).
-    Pure rank/select prefix arithmetic — a single sequential pass.
-    """
+def _slot_fingerprints(cfg: QFConfig, state: QFState):
+    """Each slot's stored fingerprint ``(fq, fr)`` in slot order, which
+    is sorted order, with sentinels in the empty slots."""
     t = cfg.total_slots
     nonempty = state.occ | state.shf  # continuation implies shifted
     run_start = nonempty & ~state.con
@@ -206,6 +201,18 @@ def extract(cfg: QFConfig, state: QFState):
     bucket_of_run = select[jnp.clip(run_id - 1, 0, t - 1)]
     fq_slot = jnp.where(nonempty, bucket_of_run, INT32_MAX)
     fr_slot = jnp.where(nonempty, state.rem, UINT32_MAX)
+    return fq_slot, fr_slot, nonempty
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def extract(cfg: QFConfig, state: QFState):
+    """Decode the filter back to sorted fingerprints.
+
+    Returns (fq, fr, n): padded (total_slots,) arrays whose first n
+    entries are the sorted fingerprint multiset (padding = sentinels).
+    Pure rank/select prefix arithmetic — a single sequential pass.
+    """
+    fq_slot, fr_slot, nonempty = _slot_fingerprints(cfg, state)
     fq_out = _compact(nonempty, fq_slot, INT32_MAX)
     fr_out = _compact(nonempty, fr_slot, UINT32_MAX)
     return fq_out, fr_out, state.n
@@ -345,16 +352,17 @@ def merge_sorted_with(
 
     The batch is sorted together with the table, so it need not be
     sorted itself: ``batch_valid`` marks its ``k`` valid rows (default:
-    the first ``k``).
+    the first ``k``).  The table joins the sort slot by slot, empty
+    slots as sentinels, so it is never compacted first.
     """
-    qs, rs, n = extract(cfg, state)
+    qs, rs, nonempty = _slot_fingerprints(cfg, state)
     allq = jnp.concatenate([qs, fq])
     allr = jnp.concatenate([rs, fr])
     if batch_valid is None:
         batch_valid = jnp.arange(fq.shape[0]) < jnp.asarray(k)
-    valid = jnp.concatenate([jnp.arange(qs.shape[0]) < n, batch_valid])
+    valid = jnp.concatenate([nonempty, batch_valid])
     allq, allr = _pad_sort(allq, allr, valid)
-    new = build(cfg, allq, allr, n + jnp.asarray(k, jnp.int32))
+    new = build(cfg, allq, allr, state.n + jnp.asarray(k, jnp.int32))
     return new._replace(overflow=new.overflow | state.overflow)
 
 
