@@ -171,8 +171,10 @@ def check_answers(ph, hits, present_mask, absent_mask, n_stored, p):
     fn = int(np.sum(present_mask & ~hits))
     fp = int(np.sum(absent_mask & hits))
     lim = fp_limit(n_stored, int(absent_mask.sum()), p)
-    ph.check(fn == 0, f"queries={hits.size} present={int(present_mask.sum())} false_negatives={fn}")
-    ph.check(fp <= lim, f"absent={int(absent_mask.sum())} false_positives={fp} limit={lim}")
+    present = int(present_mask.sum())
+    ph.check(fn == 0, f"queries={hits.size} present={present} false_negatives={fn}")
+    absent = int(absent_mask.sum())
+    ph.check(fp <= lim, f"absent={absent} false_positives={fp} limit={lim}")
 
 
 # ---------------------------------------------------------------------------
@@ -203,11 +205,14 @@ def qf_phase(
     p = q + r
 
     kb = jax.ShapeDtypeStruct((batch,), jnp.uint32)
-    insert = ph.compile("insert", lambda s, k: filters.insert(cfg, s, k), st, kb, donate=(0,))
+    insert = ph.compile(
+        "insert", lambda s, k: filters.insert(cfg, s, k), st, kb, donate=(0,)
+    )
     for i in range(n_ins // batch):
         st = ph.run(insert, st, jnp.asarray(ins[i * batch : (i + 1) * batch]))
         if i % 8 == 7:
-            log(f"[{ph.name}] inserted {(i + 1) * batch} keys, {ph.run_s['insert']:.3f}s")
+            done = (i + 1) * batch
+            log(f"[{ph.name}] inserted {done} keys, {ph.run_s['insert']:.3f}s")
     stats = jax.device_get(filters.stats(cfg, st))
     ph.check(
         int(stats["n"]) == n_ins and not stats["overflow"],
@@ -216,7 +221,9 @@ def qf_phase(
 
     # queries: every (n_ins // nq)-th inserted key, then nq absent keys
     queries = np.concatenate([ins[:: n_ins // nq][:nq], absent])
-    contains = ph.compile("contains", lambda s, k: filters.contains(cfg, s, k), st, jnp.asarray(queries))
+    contains = ph.compile(
+        "contains", lambda s, k: filters.contains(cfg, s, k), st, jnp.asarray(queries)
+    )
     hits = np.asarray(ph.run(contains, st, jnp.asarray(queries)))
     is_in = member(ins, queries)
     check_answers(ph, hits, is_in, ~is_in, n_ins, p)
@@ -235,12 +242,17 @@ def qf_phase(
     st = ph.run(delete, st, jnp.asarray(gone))
     kept = ins[ndel:]
     stats = jax.device_get(filters.stats(cfg, st))
-    ph.check(int(stats["n"]) == n_ins - ndel, f"deleted={ndel} stored={int(stats['n'])}")
+    stored = int(stats["n"])
+    ph.check(stored == n_ins - ndel, f"deleted={ndel} stored={stored}")
     queries2 = np.concatenate([gone, queries[ndel:]])
     hits2 = np.asarray(ph.run(contains, st, jnp.asarray(queries2)))
     shared = member(fingerprints(kept, p), fingerprints(gone, p))
     wrong = int(np.sum(hits2[:ndel] != shared))
-    ph.check(wrong == 0, f"deleted keys answering present={int(hits2[:ndel].sum())} sharing a stored fingerprint={int(shared.sum())} mismatches={wrong}")
+    ph.check(
+        wrong == 0,
+        f"deleted keys answering present={int(hits2[:ndel].sum())} "
+        f"sharing a stored fingerprint={int(shared.sum())} mismatches={wrong}",
+    )
     is_in2 = member(kept, queries2)
     is_in2[:ndel] = False
     rest = np.arange(queries2.size) >= ndel
@@ -250,7 +262,14 @@ def qf_phase(
 
 
 def cascade_phase(
-    seed, devices, ram_q=18, p=44, n_ins=2**25, batch=2**16, nq=2**20, require_kernel=True
+    seed,
+    devices,
+    ram_q=18,
+    p=44,
+    n_ins=2**25,
+    batch=2**16,
+    nq=2**20,
+    require_kernel=True,
 ):
     import jax
     import jax.numpy as jnp
@@ -276,7 +295,8 @@ def cascade_phase(
     levels = [int(v) for v in stats["level_counts"]]
     ph.check(
         int(stats["n"]) == n_ins and not stats["overflow"] and levels[-1] > 0,
-        f"keys={n_ins} stored={int(stats['n'])} level_counts={levels} merges={int(stats['merges'])} overflow={bool(stats['overflow'])}",
+        f"keys={n_ins} stored={int(stats['n'])} level_counts={levels} "
+        f"merges={int(stats['merges'])} overflow={bool(stats['overflow'])}",
     )
 
     queries = jnp.asarray(np.concatenate([ins[:: n_ins // nq][:nq], absent]))
@@ -319,7 +339,12 @@ def sharded_phase(
 
     kb = jax.ShapeDtypeStruct((batch,), jnp.uint32, sharding=on_mesh)
     insert = ph.compile(
-        "insert", lambda s, k: filters.insert(cfg, s, k), st, kb, donate=(0,), kernel=False
+        "insert",
+        lambda s, k: filters.insert(cfg, s, k),
+        st,
+        kb,
+        donate=(0,),
+        kernel=False,
     )
     for i in range(n_ins // batch):
         st = ph.run(insert, st, put(ins[i * batch : (i + 1) * batch]))
@@ -327,7 +352,8 @@ def sharded_phase(
     shard_n = [int(v) for v in stats["shard_counts"]]
     ph.check(
         int(stats["n"]) == n_ins and not stats["overflow"],
-        f"keys={n_ins} stored={int(stats['n'])} shard_counts={shard_n} overflow={bool(stats['overflow'])}",
+        f"keys={n_ins} stored={int(stats['n'])} shard_counts={shard_n} "
+        f"overflow={bool(stats['overflow'])}",
     )
     per_dev = {s.device.id: s.data.nbytes for s in st.rem.addressable_shards}
     ph.check(
@@ -340,7 +366,11 @@ def sharded_phase(
     queries = np.concatenate([ins[:: n_ins // nq][:nq], absent])
     chunks = [put(queries[i : i + qbatch]) for i in range(0, queries.size, qbatch)]
     contains = ph.compile(
-        "contains", lambda s, k: filters.contains(cfg, s, k), st, chunks[0], kernel=False
+        "contains",
+        lambda s, k: filters.contains(cfg, s, k),
+        st,
+        chunks[0],
+        kernel=False,
     )
     hits = np.concatenate([np.asarray(ph.run(contains, st, c)) for c in chunks])
     is_in = member(ins, queries)
@@ -398,7 +428,8 @@ def main(argv=None) -> int:
         return 1
     if len(devices) < args.chips:
         print(
-            f"chip_smoke: --chips {args.chips} needs {args.chips} TPUs, found {len(devices)}",
+            f"chip_smoke: --chips {args.chips} needs {args.chips} TPUs, "
+            f"found {len(devices)}",
             file=sys.stderr,
         )
         return 1
@@ -409,7 +440,10 @@ def main(argv=None) -> int:
 
     mode = dispatch.default_mode()
     if mode != "mosaic":
-        print(f"chip_smoke: kernel mode resolves to {mode!r}, not 'mosaic'", file=sys.stderr)
+        print(
+            f"chip_smoke: kernel mode resolves to {mode!r}, not 'mosaic'",
+            file=sys.stderr,
+        )
         return 1
     log(
         f"device_kind={devices[0].device_kind!r} count={len(devices)} "

@@ -163,7 +163,8 @@ def build_sorted(cfg: QFConfig, fq: jnp.ndarray, fr: jnp.ndarray, n) -> QFState:
     rem = jnp.zeros((t,), jnp.uint32).at[spos].set(fr, **put)
     shf = jnp.zeros((t,), jnp.bool_).at[spos].set(shf_bits, **put)
     con = jnp.zeros((t,), jnp.bool_).at[spos].set(con_bits, **put)
-    occ = jnp.zeros((t,), jnp.bool_).at[jnp.where(valid, fq, INT32_MAX)].set(True, **put)
+    occ_at = jnp.where(valid, fq, INT32_MAX)
+    occ = jnp.zeros((t,), jnp.bool_).at[occ_at].set(True, **put)
     return QFState(rem=rem, occ=occ, shf=shf, con=con, n=nn, overflow=overflow)
 
 

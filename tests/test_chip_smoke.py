@@ -31,7 +31,9 @@ def cs():
     return mod
 
 
-@pytest.mark.parametrize("q,r,seed", [(28, 16, 0), (18, 26, 0), (12, 32, 3), (10, 4, 1)])
+@pytest.mark.parametrize(
+    "q,r,seed", [(28, 16, 0), (18, 26, 0), (12, 32, 3), (10, 4, 1)]
+)
 def test_numpy_fingerprints_match_filter_hash(cs, q, r, seed):
     keys = cs.distinct_keys(seed, 4096)
     fq, fr = fingerprint(jnp.asarray(keys), q, r, seed)
@@ -61,12 +63,9 @@ def test_phases_pass_at_toy_size_with_interpreted_kernels(cs, monkeypatch):
     delete check's exception path is exercised too."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     devices = jax.devices()[:1]
-    cs.qf_phase(
-        0, devices, q=10, r=4, n_ins=512, batch=128, nq=128, ndel=64, require_kernel=False
-    )
-    cs.cascade_phase(
-        0, devices, ram_q=8, p=30, n_ins=1 << 14, batch=512, nq=512, require_kernel=False
-    )
+    toy = dict(require_kernel=False)
+    cs.qf_phase(0, devices, q=10, r=4, n_ins=512, batch=128, nq=128, ndel=64, **toy)
+    cs.cascade_phase(0, devices, ram_q=8, p=30, n_ins=1 << 14, batch=512, nq=512, **toy)
 
 
 def test_sharded_phase_on_four_virtual_devices():

@@ -80,7 +80,8 @@ def test_train_step_multidevice_matches_single():
         step0 = ts.make_train_step(cfg, ocfg)
         _, m0 = jax.jit(step0)(state, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        AUTO = jax.sharding.AxisType.Auto
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
         step_j, rules = ts.jit_train_step(cfg, ocfg, mesh, donate=False)
         with mesh:
             _, m1 = step_j(state, batch)
@@ -111,7 +112,8 @@ def test_decode_multidevice_matches_single():
         tok = batch["tokens"][:, -1:]
         ref, _ = model.decode_step(params, cfg, cache, tok)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        AUTO = jax.sharding.AxisType.Auto
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AUTO, AUTO))
         rules = shd.ShardingRules.for_config(mesh, cfg, decode=True)
         def serve(params, cache, tokens):
             with shd.use_rules(rules):
@@ -175,7 +177,8 @@ def test_elastic_restore_to_smaller_mesh():
         mgr = CheckpointManager(d)
         mgr.save(7, state)
 
-        mesh2 = jax.make_mesh((1, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        AUTO = jax.sharding.AxisType.Auto
+        mesh2 = jax.make_mesh((1, 4), ("data", "model"), axis_types=(AUTO, AUTO))
         rules2 = shd.ShardingRules.for_config(mesh2, cfg)
         sspec = ts.state_pspecs(cfg, ocfg, rules2)
         sh = jax.tree.map(lambda s: NamedSharding(mesh2, s), sspec,
