@@ -46,6 +46,9 @@ import time
 
 import numpy as np
 
+# the TPU compiler would otherwise log to a fixed directory under /tmp
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
 ROOT = os.path.dirname(os.path.abspath(__file__))
 MASK32 = np.uint64(0xFFFFFFFF)
 
@@ -114,28 +117,27 @@ def log(msg: str) -> None:
 
 
 class Phase:
-    """Times one phase's compiles and runs, checks, and its memory.
+    """Times one phase's compiles and runs, checks, and its memory."""
 
-    With ``require_kernel`` every compiled program on the kernel path
-    must hold a Mosaic kernel; it is off only where the phases are
-    rehearsed off the chip with interpreted kernels."""
-
-    def __init__(self, name: str, require_kernel: bool = True):
+    def __init__(self, name: str):
         self.name = name
-        self.require_kernel = require_kernel
         self.compile_s = {}
         self.run_s = {}
         log(f"[{name}] start")
 
     def compile(self, label, fn, *args, donate=(), kernel=True):
-        """AOT-compile ``fn`` for ``args``; with ``kernel`` the program
-        must contain a Mosaic kernel (no xla or interpret fallback)."""
+        """AOT-compile ``fn`` for ``args``.  With ``kernel``, wherever the
+        kernel mode resolves to ``mosaic`` the program must contain a
+        Mosaic kernel (no xla or interpret fallback)."""
         import jax
+
+        from repro.kernels import dispatch
 
         t0 = time.perf_counter()
         exe = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
         self.compile_s[label] = time.perf_counter() - t0
-        if kernel and self.require_kernel and "tpu_custom_call" not in exe.as_text():
+        mosaic = dispatch.default_mode() == "mosaic"
+        if kernel and mosaic and "tpu_custom_call" not in exe.as_text():
             raise AssertionError(f"{self.name}: {label} holds no Mosaic kernel")
         log(f"[{self.name}] compiled {label} in {self.compile_s[label]:.3f}s")
         return exe, label
@@ -191,14 +193,13 @@ def qf_phase(
     batch=2**22,
     nq=2**20,
     ndel=2**16,
-    require_kernel=True,
 ):
     import jax
     import jax.numpy as jnp
 
     from repro import filters
 
-    ph = Phase(f"qf q={q} r={r}", require_kernel)
+    ph = Phase(f"qf q={q} r={r}")
     keys = distinct_keys(seed, n_ins + nq)
     ins, absent = keys[:n_ins], keys[n_ins:]
     cfg, st = filters.make("qf", q=q, r=r, backend="pallas")
@@ -269,14 +270,13 @@ def cascade_phase(
     n_ins=2**25,
     batch=2**16,
     nq=2**20,
-    require_kernel=True,
 ):
     import jax
     import jax.numpy as jnp
 
     from repro import filters
 
-    ph = Phase(f"cascade ram_q={ram_q} p={p} fanout=4 levels=4", require_kernel)
+    ph = Phase(f"cascade ram_q={ram_q} p={p} fanout=4 levels=4")
     keys = distinct_keys(seed + 1, n_ins + nq)
     ins, absent = keys[:n_ins], keys[n_ins:]
     cfg, st = filters.make(

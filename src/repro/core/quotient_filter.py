@@ -349,7 +349,7 @@ def contains(cfg: QFConfig, state: QFState, keys: jnp.ndarray, window: int = 256
 def merge_sorted_with(
     cfg: QFConfig, state: QFState, fq, fr, k, build, batch_valid=None
 ) -> QFState:
-    """insert_sorted body with a pluggable build pass (reference or kernel).
+    """insert_batch body with a pluggable build pass (reference or kernel).
 
     The batch is sorted together with the table, so it need not be
     sorted itself: ``batch_valid`` marks its ``k`` valid rows (default:
@@ -368,14 +368,16 @@ def merge_sorted_with(
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def insert_sorted(cfg: QFConfig, state: QFState, fq, fr, k) -> QFState:
-    """Insert a sorted batch of k fingerprints (merge + rebuild).
+def insert_batch(cfg: QFConfig, state: QFState, fq, fr, valid) -> QFState:
+    """Insert the fingerprints where ``valid`` is set (merge + rebuild).
 
     This is the paper's merge-sort write path: one streaming pass over
     the filter — sequential I/O in the paper, sequential HBM traffic
-    here.  Duplicates are kept (QF is a multiset).
+    here.  The batch needs no order.  Duplicates are kept (QF is a
+    multiset).
     """
-    return merge_sorted_with(cfg, state, fq, fr, k, build_sorted)
+    k = jnp.sum(valid, dtype=jnp.int32)
+    return merge_sorted_with(cfg, state, fq, fr, k, build_sorted, batch_valid=valid)
 
 
 def insert(cfg: QFConfig, state: QFState, keys: jnp.ndarray, k=None) -> QFState:
@@ -383,9 +385,7 @@ def insert(cfg: QFConfig, state: QFState, keys: jnp.ndarray, k=None) -> QFState:
     if k is None:
         k = keys.shape[0]
     fq, fr = fingerprints(cfg, keys)
-    idx = jnp.arange(keys.shape[0])
-    fq, fr = _pad_sort(fq, fr, idx < jnp.asarray(k))
-    return insert_sorted(cfg, state, fq, fr, k)
+    return insert_batch(cfg, state, fq, fr, jnp.arange(keys.shape[0]) < k)
 
 
 @functools.partial(jax.jit, static_argnums=0)

@@ -139,9 +139,8 @@ def make_insert(cfg: ShardedQFConfig, mesh: Mesh, batch: int):
         bfr = jax.lax.all_to_all(bfr, axis, 0, 0, tiled=True)
         bvalid = jax.lax.all_to_all(bvalid, axis, 0, 0, tiled=True)
         q_flat, r_flat, v_flat = bq.reshape(-1), bfr.reshape(-1), bvalid.reshape(-1)
-        qs, rs = qf._pad_sort(q_flat, r_flat, v_flat)
         st0 = jax.tree.map(lambda x: x[0], st)
-        new = qf.insert_sorted(local, st0, qs, rs, jnp.sum(v_flat, dtype=jnp.int32))
+        new = qf.insert_batch(local, st0, q_flat, r_flat, v_flat)
         return jax.tree.map(lambda x: x[None], new)
 
     def insert(state, keys):

@@ -13,7 +13,7 @@ window-prefetch scheme rewards:
 * **insert** — the write side mirrors ``qf_build``: ALL k*B touched
   cell indices are sorted, so the items landing in an S-cell output
   tile are one contiguous range whose item-block is scalar-prefetched;
-  the kernel reduces one-hot match matrices (``qf_build.scatter_rows``)
+  the kernel reduces one-hot match matrices (``lanes.scatter_rows``)
   into per-cell hit COUNTS.  Counts compose with any cell plane: ``cells + counts``
   (counting), ``cells | (counts > 0)`` (plain bits), ``cells - counts``
   (counting delete) — and because the aggregation is commutative, tiles
@@ -29,8 +29,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import dispatch, lanes
-from .qf_build import launch_tiles as _launch_tiles
-from .qf_build import scatter_rows, tile_base
 
 
 def _make_probe_kernel(k: int):
@@ -112,9 +110,9 @@ def bloom_probe_tiles(
 
 
 def _count_kernel(blk_ref, first_ref, idx_a, idx_b, cnt_o):
-    base = tile_base(first_ref, cnt_o)
+    base = dispatch.tile_base(first_ref, cnt_o)
     idx = jnp.concatenate([idx_a[...], idx_b[...]], axis=0)
-    scatter_rows(idx, [jnp.ones_like(idx)], [cnt_o], base)
+    lanes.scatter_rows(idx, [jnp.ones_like(idx)], [cnt_o], base)
 
 
 def bloom_count_tiles(
@@ -150,7 +148,7 @@ def bloom_count_tiles(
     fits = ends <= (blk + 2) * S
 
     (cnt2,) = dispatch.concat_launches(
-        _launch_tiles(_count_kernel, blk, [idx2], S, 1, interpret, s, n)
+        dispatch.launch_tiles(_count_kernel, blk, [idx2], S, 1, interpret, s, n)
         for s, n in dispatch.launches(n_tiles, 1)
     )
     return cnt2.reshape(n_tiles * S), fits

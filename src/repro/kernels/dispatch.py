@@ -34,6 +34,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import lanes
 
@@ -171,6 +172,38 @@ def launches(n_tiles: int, words_per_tile: int):
 def concat_launches(parts):
     """Join per-launch output lists along the tile axis."""
     return [jnp.concatenate(p, axis=0) if len(p) > 1 else p[0] for p in zip(*parts)]
+
+
+def tile_base(first_ref, out_ref):
+    """Absolute first slot of this program's output tile: the launch's
+    first tile (scalar ``first_ref[0]``) plus the grid position."""
+    return (first_ref[0] + pl.program_id(0)) * out_ref.shape[0] * out_ref.shape[1]
+
+
+def launch_tiles(kernel, blk, items, S, n_out, interpret, s, n):
+    """One launch of a scatter grid over output tiles ``[s, s + n)``.
+
+    ``items`` are ``(rows, 128)`` item planes, each passed as the two
+    consecutive ``S``-item blocks from ``blk[t]``; the kernel writes
+    ``n_out`` lane-dense ``(S // 128, 128)`` int32 output tiles.  Its
+    scalar refs are ``blk`` and the launch's first tile ``s``.
+    """
+    win = lambda off: window_spec(S, off)
+    out = pl.BlockSpec((S // lanes.LANES, lanes.LANES), lambda t, *_: (t, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n,),
+        in_specs=[win(off) for _ in items for off in (0, 1)],
+        out_specs=[out] * n_out,
+    )
+    plane = jax.ShapeDtypeStruct((n * S // lanes.LANES, lanes.LANES), jnp.int32)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=[plane] * n_out, interpret=interpret
+    )(
+        blk[s : s + n],
+        jnp.full((1,), s, jnp.int32),
+        *(x for x in items for _ in (0, 1)),
+    )
 
 
 def window_base(

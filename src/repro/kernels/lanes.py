@@ -83,3 +83,27 @@ def gather(win: jnp.ndarray, rel: jnp.ndarray) -> jnp.ndarray:
     for i in range(rows):
         acc = acc + jnp.where(lane == (rel - i * lanes), win[i : i + 1, :], 0)
     return jnp.sum(acc, axis=1, keepdims=True)
+
+
+def scatter_rows(pos, vals, out_refs, base):
+    """Reduce items onto lane-dense output rows: ``out[p - base] = v``.
+
+    ``pos`` is an ``(rows, 128)`` int32 item block, ``vals`` a list of
+    item blocks of the same shape, ``out_refs`` one ``(sr, 128)`` output
+    ref per value.  Items whose position falls outside the ``sr * 128``
+    output slots contribute nothing; several items on one slot add up
+    (callers with unique positions get a plain scatter).
+    """
+    sr, width = out_refs[0].shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (width, width), 1)
+    acc = [[jnp.zeros((width, width), jnp.int32)] * sr for _ in out_refs]
+    for k in range(pos.shape[0]):
+        p = row_to_col(pos[k : k + 1, :]) - base
+        cols = [row_to_col(v[k : k + 1, :]) for v in vals]
+        for c in range(sr):
+            hit = (p - c * width) == lane
+            for a, col in zip(acc, cols):
+                a[c] = a[c] + jnp.where(hit, col, 0)
+    for ref, a in zip(out_refs, acc):
+        for c in range(sr):
+            ref[c : c + 1, :] = jnp.sum(a[c], axis=0, keepdims=True)

@@ -23,77 +23,18 @@ is_shifted/is_continuation), which dominate bytes.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import dispatch, lanes
-
-
-def scatter_rows(pos, vals, out_refs, base):
-    """Reduce items onto lane-dense output rows: ``out[p - base] = v``.
-
-    ``pos`` is an ``(rows, 128)`` int32 item block, ``vals`` a list of
-    item blocks of the same shape, ``out_refs`` one ``(sr, 128)`` output
-    ref per value.  Items whose position falls outside the ``sr * 128``
-    output slots contribute nothing; several items on one slot add up
-    (callers with unique positions get a plain scatter).
-    """
-    sr, width = out_refs[0].shape
-    lane = jax.lax.broadcasted_iota(jnp.int32, (width, width), 1)
-    acc = [[jnp.zeros((width, width), jnp.int32)] * sr for _ in out_refs]
-    for k in range(pos.shape[0]):
-        p = lanes.row_to_col(pos[k : k + 1, :]) - base
-        cols = [lanes.row_to_col(v[k : k + 1, :]) for v in vals]
-        for c in range(sr):
-            hit = (p - c * width) == lane
-            for a, col in zip(acc, cols):
-                a[c] = a[c] + jnp.where(hit, col, 0)
-    for ref, a in zip(out_refs, acc):
-        for c in range(sr):
-            ref[c : c + 1, :] = jnp.sum(a[c], axis=0, keepdims=True)
-
-
-def tile_base(first_ref, out_ref):
-    """Absolute first slot of this program's output tile: the launch's
-    first tile (scalar ``first_ref[0]``) plus the grid position."""
-    return (first_ref[0] + pl.program_id(0)) * out_ref.shape[0] * out_ref.shape[1]
 
 
 def _build_kernel(
     blk_ref, first_ref, pos_a, pos_b, fr_a, fr_b, mb_a, mb_b, rem_o, meta_o
 ):
-    base = tile_base(first_ref, rem_o)
+    base = dispatch.tile_base(first_ref, rem_o)
     cat = lambda a, b: jnp.concatenate([a[...], b[...]], axis=0)
-    scatter_rows(
+    lanes.scatter_rows(
         cat(pos_a, pos_b), [cat(fr_a, fr_b), cat(mb_a, mb_b)], [rem_o, meta_o], base
-    )
-
-
-def launch_tiles(kernel, blk, items, S, n_out, interpret, s, n):
-    """One launch of a scatter grid over output tiles ``[s, s + n)``.
-
-    ``items`` are ``(rows, 128)`` item planes, each passed as the two
-    consecutive ``S``-item blocks from ``blk[t]``; the kernel writes
-    ``n_out`` lane-dense ``(S // 128, 128)`` int32 output tiles.  Its
-    scalar refs are ``blk`` and the launch's first tile ``s``.
-    """
-    win = lambda off: dispatch.window_spec(S, off)
-    out = pl.BlockSpec((S // lanes.LANES, lanes.LANES), lambda t, *_: (t, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n,),
-        in_specs=[win(off) for _ in items for off in (0, 1)],
-        out_specs=[out] * n_out,
-    )
-    plane = jax.ShapeDtypeStruct((n * S // lanes.LANES, lanes.LANES), jnp.int32)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=[plane] * n_out, interpret=interpret
-    )(
-        blk[s : s + n],
-        jnp.full((1,), s, jnp.int32),
-        *(x for x in items for _ in (0, 1)),
     )
 
 
@@ -133,7 +74,9 @@ def qf_build_planes(
     blk = jnp.minimum(starts // S, n_blocks - 2).astype(jnp.int32)
 
     rem2, meta2 = dispatch.concat_launches(
-        launch_tiles(_build_kernel, blk, [pos2, fr2, mb2], S, 2, interpret, s, n)
+        dispatch.launch_tiles(
+            _build_kernel, blk, [pos2, fr2, mb2], S, 2, interpret, s, n
+        )
         for s, n in dispatch.launches(n_tiles, 1)
     )
     rem = rem2.reshape(t_pad)[:total_slots]

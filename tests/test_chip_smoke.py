@@ -63,9 +63,8 @@ def test_phases_pass_at_toy_size_with_interpreted_kernels(cs, monkeypatch):
     delete check's exception path is exercised too."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     devices = jax.devices()[:1]
-    toy = dict(require_kernel=False)
-    cs.qf_phase(0, devices, q=10, r=4, n_ins=512, batch=128, nq=128, ndel=64, **toy)
-    cs.cascade_phase(0, devices, ram_q=8, p=30, n_ins=1 << 14, batch=512, nq=512, **toy)
+    cs.qf_phase(0, devices, q=10, r=4, n_ins=512, batch=128, nq=128, ndel=64)
+    cs.cascade_phase(0, devices, ram_q=8, p=30, n_ins=1 << 14, batch=512, nq=512)
 
 
 def test_sharded_phase_on_four_virtual_devices():

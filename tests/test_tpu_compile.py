@@ -9,6 +9,8 @@ and check that each program really holds a Mosaic kernel.  They pass
 the tests run on.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -29,6 +31,8 @@ BATCH = 1 << 16
 def topo():
     from jax.experimental import topologies
 
+    # the TPU compiler would otherwise log to a fixed directory under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
