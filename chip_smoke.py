@@ -348,6 +348,9 @@ def sharded_phase(
     )
     for i in range(n_ins // batch):
         st = ph.run(insert, st, put(ins[i * batch : (i + 1) * batch]))
+        if i % 8 == 7:
+            done = (i + 1) * batch
+            log(f"[{ph.name}] inserted {done} keys, {ph.run_s['insert']:.3f}s")
     stats = jax.device_get(filters.stats(cfg, st))
     shard_n = [int(v) for v in stats["shard_counts"]]
     ph.check(
