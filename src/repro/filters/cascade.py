@@ -176,6 +176,14 @@ def _level_stream(cfg: CascadeConfig, state: CascadeState, i: int):
     return fq, fr, n
 
 
+def _slot_stream(cfg: CascadeConfig, c: qf.QFConfig, s):
+    """A QF's fingerprints slot by slot in the canonical split, with the
+    mask of stored slots — no compaction, so not yet one sorted run."""
+    fq, fr, stored = qf._slot_fingerprints(c, s)
+    fq, fr = qf._requotient(fq, fr, c, _canon_cfg(cfg))
+    return fq, fr, stored
+
+
 def _q0_stream(cfg: CascadeConfig, state: CascadeState):
     fq, fr, n = qf.extract(cfg.q0_cfg, state.q0)
     fq, fr = qf._requotient(fq, fr, cfg.q0_cfg, _canon_cfg(cfg))
@@ -222,14 +230,28 @@ def _build_level_traced(cfg: CascadeConfig, i: int, allq, allr, total):
 def _collapse_into(cfg: CascadeConfig, state: CascadeState, i: int) -> CascadeState:
     """Merge Q0..Q_i into a fresh Q_i; levels above i empty (paper Fig. 5).
 
-    Every participant streams in the canonical split and the fold is
-    rank arithmetic (``merge_streams_many``, sort-free); a frozen target
-    peels on device, so the whole collapse — demotions included — stays
-    inside the ``lax.switch`` branch."""
-    parts = [_q0_stream(cfg, state)] + [
-        _level_stream(cfg, state, j) for j in range(i + 1)
-    ]
-    allq, allr, total = qf.merge_streams_many(parts)
+    Every participant joins in the canonical split — a QF as its slot
+    stream, a frozen level as its retained run — and one unstable sort
+    over the concatenation orders them, as the flat QF's insert does
+    (equal fingerprints are identical, so their order cannot matter).
+    A rank-arithmetic fold (``merge_streams_many``) would binary-search
+    every element of every level, one gather per step, which on a TPU
+    costs far more than the sort.  A frozen target peels on device, so
+    the whole collapse — demotions included — stays inside the
+    ``lax.switch`` branch."""
+    parts = [_slot_stream(cfg, cfg.q0_cfg, state.q0)]
+    total = state.q0.n
+    for j in range(i + 1):
+        s = state.levels[j]
+        if cfg.is_frozen(j):
+            fq, fr, n = fuse.extract_run(cfg.fuse_cfg(j), s)
+            parts.append((fq, fr, jnp.arange(fq.shape[0]) < n))
+        else:
+            parts.append(_slot_stream(cfg, cfg.level_cfg(j), s))
+        total = total + s.n
+    allq, allr = qf._pad_sort(
+        *(jnp.concatenate([part[k] for part in parts]) for k in range(3))
+    )
     overflow = state.q0.overflow
     for j in range(i + 1):
         overflow = overflow | state.levels[j].overflow
