@@ -40,6 +40,7 @@ def load_toml(path: str) -> dict:
 JITTABLE_OPS = {
     "insert",
     "contains",
+    "contains_stats",
     "delete",
     "merge",
     "probe",

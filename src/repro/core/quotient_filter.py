@@ -120,6 +120,7 @@ def fingerprints(cfg: QFConfig, keys: jnp.ndarray):
     return fingerprint(keys, cfg.q, cfg.r, cfg.seed)
 
 
+@jax.named_scope("qf.sort")
 def _pad_sort(fq: jnp.ndarray, fr: jnp.ndarray, valid: jnp.ndarray):
     """Sort (fq, fr) lexicographically, pushing invalid entries to the end."""
     fq = jnp.where(valid, fq, INT32_MAX)
@@ -136,6 +137,7 @@ def _pad_sort(fq: jnp.ndarray, fr: jnp.ndarray, valid: jnp.ndarray):
 
 
 @functools.partial(jax.jit, static_argnums=0)
+@jax.named_scope("qf.build")
 def build_sorted(cfg: QFConfig, fq: jnp.ndarray, fr: jnp.ndarray, n) -> QFState:
     """Build a QF from lexicographically sorted (fq, fr), first ``n`` valid.
 
@@ -186,6 +188,7 @@ def _compact(keep: jnp.ndarray, values: jnp.ndarray, fill) -> jnp.ndarray:
     return out[1::2]
 
 
+@jax.named_scope("qf.decode")
 def _slot_fingerprints(cfg: QFConfig, state: QFState):
     """Each slot's stored fingerprint ``(fq, fr)`` in slot order, which
     is sorted order, with sentinels in the empty slots."""
@@ -206,6 +209,7 @@ def _slot_fingerprints(cfg: QFConfig, state: QFState):
 
 
 @functools.partial(jax.jit, static_argnums=0)
+@jax.named_scope("qf.decode")
 def extract(cfg: QFConfig, state: QFState):
     """Decode the filter back to sorted fingerprints.
 
@@ -305,9 +309,15 @@ def _window_decode(cfg: QFConfig, state: QFState, fq, fr, W: int):
     return present, overflow
 
 
-@functools.partial(jax.jit, static_argnums=(0, 4))
+@functools.partial(jax.jit, static_argnums=(0, 4), static_argnames=("with_stats",))
 def lookup(
-    cfg: QFConfig, state: QFState, fq: jnp.ndarray, fr: jnp.ndarray, window: int = 256
+    cfg: QFConfig,
+    state: QFState,
+    fq: jnp.ndarray,
+    fr: jnp.ndarray,
+    window: int = 256,
+    *,
+    with_stats: bool = False,
 ):
     """MAY-CONTAIN for a batch of fingerprints (paper Fig. 3, vectorized).
 
@@ -315,30 +325,53 @@ def lookup(
     TPU analogue of the paper's single-page cluster access).  Queries
     whose cluster exceeds the window (whp-rare; paper §3 Fact) retry at
     4x the window, then fall back to the exact decode path.
+
+    ``with_stats=True`` returns ``(present, stats)``, ``stats`` a dict
+    of int32 device scalars from the same program: ``queries``,
+    ``queries_retry`` (first window overflowed), ``queries_exact``
+    (answered by the exact decode) and ``exact_passes`` (0 or 1
+    whole-table decodes).
     """
     present, ovf = _window_decode(cfg, state, fq, fr, window)
+
+    @jax.named_scope("qf.exact")
+    def exact(args):
+        present, o2 = args
+        pe = lookup_exact(cfg, state, fq, fr)
+        return jnp.where(o2, pe, present)
 
     def retry(args):
         present, ovf = args
         p2, o2 = _window_decode(cfg, state, fq, fr, min(4 * window, cfg.m))
         present = jnp.where(ovf, p2, present)
-
-        def exact(args):
-            present, o2 = args
-            pe = lookup_exact(cfg, state, fq, fr)
-            return jnp.where(o2, pe, present)
-
-        return jax.lax.cond(
+        present = jax.lax.cond(
             jnp.any(o2), exact, lambda a: a[0], (present, ovf & o2)
         )
+        return (present, o2) if with_stats else present
 
-    return jax.lax.cond(jnp.any(ovf), retry, lambda a: a[0], (present, ovf))
+    if not with_stats:
+        return jax.lax.cond(jnp.any(ovf), retry, lambda a: a[0], (present, ovf))
+    no_retry = lambda a: (a[0], jnp.zeros_like(a[1]))
+    present, o2 = jax.lax.cond(jnp.any(ovf), retry, no_retry, (present, ovf))
+    return present, {
+        "queries": jnp.asarray(fq.shape[0], jnp.int32),
+        "queries_retry": jnp.sum(ovf, dtype=jnp.int32),
+        "queries_exact": jnp.sum(ovf & o2, dtype=jnp.int32),
+        "exact_passes": jnp.any(o2).astype(jnp.int32),
+    }
 
 
-def contains(cfg: QFConfig, state: QFState, keys: jnp.ndarray, window: int = 256):
-    """Key-level MAY-CONTAIN."""
+def contains(
+    cfg: QFConfig,
+    state: QFState,
+    keys: jnp.ndarray,
+    window: int = 256,
+    *,
+    with_stats: bool = False,
+):
+    """Key-level MAY-CONTAIN (``with_stats``: see :func:`lookup`)."""
     fq, fr = fingerprints(cfg, keys)
-    return lookup(cfg, state, fq, fr, window)
+    return lookup(cfg, state, fq, fr, window, with_stats=with_stats)
 
 
 # ---------------------------------------------------------------------------

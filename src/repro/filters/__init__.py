@@ -97,6 +97,7 @@ _OPS = frozenset(
     {
         "insert",
         "contains",
+        "contains_stats",
         "delete",
         "merge",
         "probe",
@@ -123,9 +124,20 @@ def insert(cfg, state, keys, k=None):
     return by_cfg(cfg).require("insert")(cfg, state, keys, k)
 
 
-def contains(cfg, state, keys):
-    """MAY-CONTAIN for a key batch (no false negatives)."""
-    return by_cfg(cfg).contains(cfg, state, keys)
+def contains(cfg, state, keys, *, with_stats=False):
+    """MAY-CONTAIN for a key batch (no false negatives).
+
+    ``with_stats=True`` returns ``(hits, stats)``: ``stats`` is a dict
+    of int32 device scalars that the same program computes, counting
+    how the batch was answered (``"qf"`` only: ``queries``, ``tiles``,
+    ``tiles_unfit``, ``queries_exact``, ``exact_passes``; README
+    "Observability").  Other families raise
+    :class:`UnsupportedOpError`.
+    """
+    impl = by_cfg(cfg)
+    if with_stats:
+        return impl.require("contains_stats")(cfg, state, keys)
+    return impl.contains(cfg, state, keys)
 
 
 def delete(cfg, state, keys, k=None):

@@ -81,10 +81,12 @@ def insert_keys(
     return insert_fingerprints(core, backend, state, fq, fr, valid_mask(keys, k))
 
 
-def contains_keys(core: qf.QFConfig, backend: str, state, keys, window=256):
+def contains_keys(
+    core: qf.QFConfig, backend: str, state, keys, window=256, with_stats=False
+):
     if backend == "pallas":
-        return kops.contains(core, state, keys)
-    return qf.contains(core, state, keys, window)
+        return kops.contains(core, state, keys, with_stats=with_stats)
+    return qf.contains(core, state, keys, window, with_stats=with_stats)
 
 
 def delete_masked(core: qf.QFConfig, state: qf.QFState, fq, fr, mask) -> qf.QFState:
@@ -134,6 +136,15 @@ def insert(cfg: QFilterConfig, state, keys, k=None):
 
 def contains(cfg: QFilterConfig, state, keys):
     return contains_keys(cfg.core, cfg.backend, state, keys, cfg.window)
+
+
+def contains_stats(cfg: QFilterConfig, state, keys):
+    """``(hits, stats)``: ``contains`` and the counters of the path that
+    answered (``kernels.ops.lookup`` under pallas, ``qf.lookup`` under
+    the reference backend)."""
+    return contains_keys(
+        cfg.core, cfg.backend, state, keys, cfg.window, with_stats=True
+    )
 
 
 def delete(cfg: QFilterConfig, state, keys, k=None):
@@ -224,6 +235,7 @@ IMPL = register(
         contains=contains,
         stats=stats,
         delete=delete,
+        contains_stats=contains_stats,
         merge=merge,
         needs_resize=needs_resize,
         grow=grow,

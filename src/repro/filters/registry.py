@@ -11,6 +11,7 @@ Protocol (all ops pure; states are pytrees; every op is jittable):
     make(**spec)                  -> (cfg, state)
     insert(cfg, state, keys, k)   -> state
     contains(cfg, state, keys)    -> bool[B]
+    contains_stats(cfg, state, keys) -> (bool[B], dict)  (optional)
     delete(cfg, state, keys, k)   -> state          (optional)
     merge(cfg, state_a, state_b)  -> state          (optional)
     probe(cfg, state, keys)       -> (state, bool[B])  # contains + I/O accounting
@@ -79,6 +80,8 @@ class FilterImpl(NamedTuple):
     stats: Callable  # (cfg, state) -> dict
     delete: Optional[Callable] = None
     merge: Optional[Callable] = None
+    # contains plus the lookup path's counters (int32 device scalars)
+    contains_stats: Optional[Callable] = None  # (cfg, state, keys) -> (bool[B], dict)
     probe: Optional[Callable] = None  # (cfg, state, keys) -> (state, bool[B])
     needs_resize: Optional[Callable] = None  # (cfg, state) -> bool[] (device)
     grow: Optional[Callable] = None  # (cfg, state) -> (cfg, state)

@@ -31,7 +31,7 @@ from .bloom_block import bloom_count_tiles, bloom_probe_tiles
 from .cascade_probe import cascade_probe_tiles
 from .fuse_probe import fuse_probe_tiles
 from .qf_build import qf_build_planes
-from .qf_probe import qf_probe_tiles
+from .qf_probe import qf_probe_tiles, tile_windows
 
 INT32_MAX = jnp.int32(2**31 - 1)
 
@@ -86,6 +86,10 @@ def _build_sorted(cfg, fq, fr, n, *, mode, block_s):
     return qf.build_sorted(cfg, fq, fr, n)
 
 
+# The scopes of the kernel paths open outside their jitted wrappers, so
+# a kernel's op keeps the wrapper's name (``%_build_sorted.N``,
+# ``%_lookup.N``) in the compiled program: traces find kernels by it.
+@jax.named_scope("qf.build")
 def build_sorted(
     cfg: qf.QFConfig,
     fq: jnp.ndarray,
@@ -114,15 +118,29 @@ def build_sorted(
 # ---------------------------------------------------------------------------
 
 
+def _probe_stats(**counts):
+    """The probe's counters as int32 device scalars."""
+    return {k: jnp.asarray(v, jnp.int32) for k, v in counts.items()}
+
+
 @functools.partial(
-    jax.jit, static_argnums=(0,), static_argnames=("mode", "tile_t", "wblk")
+    jax.jit,
+    static_argnums=(0,),
+    static_argnames=("mode", "tile_t", "wblk", "with_stats"),
 )
-def _lookup(cfg, state, fq, fr, *, mode, tile_t, wblk):
+def _lookup(cfg, state, fq, fr, *, mode, tile_t, wblk, with_stats):
     if not dispatch.is_pallas(mode):
         # xla mode: decode the table once, binary-search the batch —
         # O(m + B log m) vs the reference's O(B * window) per-query
         # cluster decode; same exact-membership answer
-        return qf.lookup_exact(cfg, state, fq, fr)
+        with jax.named_scope("qf.exact"):
+            present = qf.lookup_exact(cfg, state, fq, fr)
+        if not with_stats:
+            return present
+        B = fq.shape[0]
+        return present, _probe_stats(
+            queries=B, tiles=0, tiles_unfit=0, queries_exact=B, exact_passes=1
+        )
 
     B0 = fq.shape[0]
     osort = dispatch.sorted_tile_order(fq, tile_t)
@@ -142,16 +160,28 @@ def _lookup(cfg, state, fq, fr, *, mode, tile_t, wblk):
     )
     present, ovf = dispatch.unpermute(osort, B0, present_s, ovf_s)
 
+    @jax.named_scope("qf.exact")
     def resolve(args):
         present, ovf = args
         exact = qf.lookup_exact(cfg, state, fq, fr)
         return jnp.where(ovf > 0, exact, present > 0)
 
-    return jax.lax.cond(
-        jnp.any(ovf > 0), resolve, lambda a: a[0] > 0, (present, ovf)
+    exact_pass = jnp.any(ovf > 0)
+    present = jax.lax.cond(exact_pass, resolve, lambda a: a[0] > 0, (present, ovf))
+    if not with_stats:
+        return present
+    fq3 = dispatch.query_tiles(fq_s, tile_t)
+    _, _, tile_fits = tile_windows(fq3, state.rem.shape[0], wblk)
+    return present, _probe_stats(
+        queries=B0,
+        tiles=fq3.shape[0],
+        tiles_unfit=jnp.sum(~tile_fits),
+        queries_exact=jnp.sum(ovf > 0),
+        exact_passes=exact_pass,
     )
 
 
+@jax.named_scope("qf.probe")  # outside the jitted wrapper, as qf.build
 def lookup(
     cfg: qf.QFConfig,
     state: qf.QFState,
@@ -162,8 +192,17 @@ def lookup(
     interpret: bool | None = None,
     tile_t: int = 128,
     wblk: int = 1024,
+    with_stats: bool = False,
 ):
-    """Mode-dispatched MAY-CONTAIN; overflows resolve on the exact path."""
+    """Mode-dispatched MAY-CONTAIN; overflows resolve on the exact path.
+
+    ``with_stats=True`` returns ``(present, stats)``, ``stats`` a dict
+    of int32 device scalars computed in the same program: ``queries``;
+    ``tiles`` (query tiles probed); ``tiles_unfit`` (tiles whose
+    quotients outrun their window); ``queries_exact`` (answered by the
+    exact path) and ``exact_passes`` (0 or 1 whole-table decodes).  In
+    xla mode every query is answered exactly, with no tiles.
+    """
     return _lookup(
         cfg,
         state,
@@ -172,6 +211,7 @@ def lookup(
         mode=dispatch.resolve(mode, interpret),
         tile_t=tile_t,
         wblk=wblk,
+        with_stats=with_stats,
     )
 
 

@@ -104,6 +104,16 @@ def _probe_kernel(
     ovf_o[0] = lanes.col_to_row(ovf.astype(jnp.int32))
 
 
+def tile_windows(fq3: jnp.ndarray, total: int, wblk: int):
+    """``(blk, wbase, fits)`` of each ``dispatch.query_tiles`` tile of
+    sorted quotients over a ``total``-slot table: its window, from its
+    first and last quotient with room for the run tail past the last
+    query, and whether the tile fits it (``dispatch.window_base``)."""
+    return dispatch.window_base(
+        fq3[:, 0, 0], fq3[:, 0, -1], total, wblk, margin=wblk // 4
+    )
+
+
 def qf_probe_tiles(
     rem: jnp.ndarray,
     occ: jnp.ndarray,
@@ -135,10 +145,7 @@ def qf_probe_tiles(
     fq3 = dispatch.query_tiles(fq_sorted, tile_t)
     fr3 = dispatch.query_tiles(fr_sorted, tile_t)
 
-    # room for the run tail past the last query
-    blk, wbase, tile_fits = dispatch.window_base(
-        fq3[:, 0, 0], fq3[:, 0, -1], total, wblk, margin=wblk // 4
-    )
+    blk, wbase, tile_fits = tile_windows(fq3, total, wblk)
 
     win = lambda off: dispatch.window_spec(wblk, off)
     qspec = dispatch.query_spec(tile_t)

@@ -142,27 +142,6 @@ class Roofline:
         }
 
 
-def kernel_roofline(fn, *args, **kw) -> "Roofline":
-    """Compiled cost-analysis of one kernel-layer op as a Roofline.
-
-    Single chip, no collectives: the filter kernels are per-device
-    streaming passes, so the roofline reduces to the compute-vs-HBM
-    pair and ``t_memory`` is the TPU projection for a bandwidth-bound
-    op.  Works on any backend — the CPU-compiled module's FLOP/byte
-    counts are the same structural quantities the TPU module streams.
-    """
-    import jax
-
-    compiled = jax.jit(fn).lower(*args, **kw).compile()
-    cost = compiled.cost_analysis() or {}
-    return Roofline(
-        flops=float(cost.get("flops", 0.0) or 0.0),
-        bytes_accessed=float(cost.get("bytes accessed", 0.0) or 0.0),
-        coll_bytes=0.0,
-        chips=1,
-    )
-
-
 def model_flops_estimate(cfg, shape_kind: str, batch: int, seq: int) -> float:
     """Analytic useful FLOPs: 6·N_active·D for training, 2·N_active·D
     (+ attention KV term) for serving."""

@@ -10,6 +10,7 @@ the tests run on.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -142,3 +143,45 @@ def test_cascade_probe_compiles_for_smoke_geometry(chip):
         [q] * len(totals),
         [q] * len(totals),
     )
+
+
+def _kernel_ops(fn, *args):
+    """``(instruction, op_name)`` of each Mosaic kernel op in the
+    compiled program."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [
+        (line.split(" = ")[0].strip(), re.search(r'op_name="([^"]*)"', line).group(1))
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+
+
+@pytest.mark.parametrize("with_stats", [False, True])
+def test_probe_kernel_keeps_its_name_inside_its_scope(chip, with_stats):
+    """A trace names the kernel's op after the jitted wrapper
+    (``bench/kernels.json``); the ``qf.probe`` scope only tags it."""
+    cfg = qf.QFConfig(q=16, r=16)
+    ops_ = _kernel_ops(
+        lambda st, k: ops.contains(cfg, st, k, mode=MOSAIC, with_stats=with_stats),
+        _state(chip, cfg),
+        _spec(chip, (4096,), jnp.uint32),
+    )
+    assert ops_
+    for name, op_name in ops_:
+        assert name.startswith("%_lookup."), name
+        assert "qf.probe" in op_name.split("/"), op_name
+
+
+def test_build_kernel_keeps_its_name_inside_its_scope(chip):
+    cfg = qf.QFConfig(q=16, r=16)
+    t = cfg.total_slots
+    ops_ = _kernel_ops(
+        lambda fq, fr, n: ops.build_sorted(cfg, fq, fr, n, mode=MOSAIC),
+        _spec(chip, (t,), jnp.int32),
+        _spec(chip, (t,), jnp.uint32),
+        _spec(chip, (), jnp.int32),
+    )
+    assert ops_
+    for name, op_name in ops_:
+        assert name.startswith("%_build_sorted."), name
+        assert "qf.build" in op_name.split("/"), op_name
