@@ -31,7 +31,7 @@ from .bloom_block import bloom_count_tiles, bloom_probe_tiles
 from .cascade_probe import cascade_probe_tiles
 from .fuse_probe import fuse_probe_tiles
 from .qf_build import qf_build_planes
-from .qf_probe import qf_probe_tiles, tile_windows
+from .qf_probe import qf_probe_tiles
 
 INT32_MAX = jnp.int32(2**31 - 1)
 
@@ -144,20 +144,19 @@ def _lookup(cfg, state, fq, fr, *, mode, tile_t, wblk, with_stats):
 
     B0 = fq.shape[0]
     osort = dispatch.sorted_tile_order(fq, tile_t)
-    fq_s = fq[osort]
-    fr_s = fr[osort]
-
-    present_s, ovf_s = qf_probe_tiles(
+    present_s, ovf_s, tiles, tiles_unfit = qf_probe_tiles(
         state.rem.astype(jnp.int32),
         state.occ.astype(jnp.int32),
         state.shf.astype(jnp.int32),
         state.con.astype(jnp.int32),
-        fq_s,
-        fr_s,
+        fq[osort],
+        fr[osort],
         tile_t=tile_t,
         wblk=wblk,
         interpret=dispatch.pallas_interpret(mode),
     )
+    # the padding copies of the last query may spill into the next tile,
+    # which shares its window, so every copy still carries the same values
     present, ovf = dispatch.unpermute(osort, B0, present_s, ovf_s)
 
     @jax.named_scope("qf.exact")
@@ -170,12 +169,10 @@ def _lookup(cfg, state, fq, fr, *, mode, tile_t, wblk, with_stats):
     present = jax.lax.cond(exact_pass, resolve, lambda a: a[0] > 0, (present, ovf))
     if not with_stats:
         return present
-    fq3 = dispatch.query_tiles(fq_s, tile_t)
-    _, _, tile_fits = tile_windows(fq3, state.rem.shape[0], wblk)
     return present, _probe_stats(
         queries=B0,
-        tiles=fq3.shape[0],
-        tiles_unfit=jnp.sum(~tile_fits),
+        tiles=tiles,
+        tiles_unfit=tiles_unfit,
         queries_exact=jnp.sum(ovf > 0),
         exact_passes=exact_pass,
     )
@@ -198,9 +195,11 @@ def lookup(
 
     ``with_stats=True`` returns ``(present, stats)``, ``stats`` a dict
     of int32 device scalars computed in the same program: ``queries``;
-    ``tiles`` (query tiles probed); ``tiles_unfit`` (tiles whose
-    quotients outrun their window); ``queries_exact`` (answered by the
-    exact path) and ``exact_passes`` (0 or 1 whole-table decodes).  In
+    ``tiles`` (live window-aligned query tiles probed); ``tiles_unfit``
+    (tiles whose quotients outrun their window: a guard, 0 by
+    construction); ``queries_exact`` (answered by the exact path: their
+    cluster outruns the window) and ``exact_passes`` (0 or 1 whole-table
+    decodes).  In
     xla mode every query is answered exactly, with no tiles.
     """
     return _lookup(

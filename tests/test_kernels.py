@@ -89,7 +89,7 @@ def test_probe_kernel_matches_ref_oracle():
     pad = (-fq_s.shape[0]) % 128
     fq_s = jnp.concatenate([fq_s, jnp.repeat(fq_s[-1:], pad)])
     fr_s = jnp.concatenate([fr_s, jnp.repeat(fr_s[-1:], pad)])
-    present, ovf = qf_probe_tiles(
+    present, ovf, _, _ = qf_probe_tiles(
         st.rem.astype(jnp.int32),
         st.occ.astype(jnp.int32),
         st.shf.astype(jnp.int32),

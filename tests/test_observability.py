@@ -70,15 +70,42 @@ def test_with_stats_answers_match_contains(monkeypatch, backend, mode, want):
 
 
 def _numpy_tile_spans(fq, total):
-    """Sorted quotients in ``TILE``-query tiles; a tile fits when its
-    span stays inside the probe's two-block window less a quarter block
-    of run tail (``qf_probe.tile_windows``).  Returns ``fits`` per tile."""
+    """The schedule before window-aligned tiles: sorted quotients in
+    consecutive ``TILE``-query tiles, each fitting when its span stays
+    inside the two-block window less a quarter block of run tail.
+    Returns ``fits`` per tile."""
     tiles = np.sort(np.asarray(fq)).reshape(-1, TILE)
     lo, hi = tiles[:, 0], tiles[:, -1]
     margin = WINDOW // 4
     n_blocks = -(-total // WINDOW) + 1
     base = np.clip((lo - margin) // WINDOW, 0, n_blocks - 2) * WINDOW
     return (hi - base) < 2 * WINDOW - margin
+
+
+def _numpy_aligned_tiles(fq, total):
+    """Window-aligned tiles of a batch padded, as the probe pads it, to
+    whole ``TILE``s with copies of its largest quotient: each query takes
+    the block its window would start at, and a block of ``c`` queries
+    makes ``ceil(c / TILE)`` tiles."""
+    fq = np.sort(np.asarray(fq))
+    fq = np.concatenate([fq, np.full((-fq.size) % TILE, fq[-1])])
+    margin = WINDOW // 4
+    n_blocks = -(-total // WINDOW) + 1
+    _, counts = np.unique(
+        np.clip((fq - margin) // WINDOW, 0, n_blocks - 2), return_counts=True
+    )
+    return int((-(-counts // TILE)).sum())
+
+
+def _probe_stats(cfg, st, fq, fr):
+    """Answers of the interpreted kernel path, checked against the exact
+    lookup, and its counters."""
+    fq, fr = jnp.asarray(fq, jnp.int32), jnp.asarray(fr, jnp.uint32)
+    hits, stats = ops.lookup(cfg, st, fq, fr, mode="interpret", with_stats=True)
+    np.testing.assert_array_equal(
+        np.asarray(hits), np.asarray(qf.lookup_exact(cfg, st, fq, fr))
+    )
+    return _as_ints(stats)
 
 
 def test_stats_count_tiles_that_outrun_the_window():
@@ -88,21 +115,82 @@ def test_stats_count_tiles_that_outrun_the_window():
     rng = np.random.default_rng(2)
     dense = rng.integers(3000, 3400, 512)  # four tiles inside one window
     spread = rng.integers(0, cfg.m, 512)  # tiles spanning ~4 windows each
-    fq = jnp.asarray(np.concatenate([dense, spread]).astype(np.int32))
-    fr = jnp.asarray(rng.integers(0, 2**16, 1024).astype(np.uint32))
-    hits, stats = ops.lookup(cfg, st, fq, fr, mode="interpret", with_stats=True)
-    np.testing.assert_array_equal(
-        np.asarray(hits), np.asarray(qf.lookup_exact(cfg, st, fq, fr))
-    )
+    fq = np.concatenate([dense, spread])
+    fr = rng.integers(0, 2**16, 1024)
+    # consecutive tiles of this batch would outrun their windows; tiles
+    # aligned to the windows all fit
     fits = _numpy_tile_spans(fq, cfg.total_slots)
     assert 0 < (~fits).sum() < fits.size
-    assert _as_ints(stats) == dict(
+    assert _probe_stats(cfg, st, fq, fr) == dict(
         queries=1024,
-        tiles=fits.size,
-        tiles_unfit=int((~fits).sum()),
-        queries_exact=int((~fits).sum()) * TILE,
-        exact_passes=1,
+        tiles=_numpy_aligned_tiles(fq, cfg.total_slots),
+        tiles_unfit=0,
+        queries_exact=0,
+        exact_passes=0,
     )
+
+
+def _quotients(case, m, rng):
+    if case == "sparse":  # every consecutive tile spans several windows
+        return rng.integers(0, m, 1024)
+    if case == "crowded_block":  # one block holds 300 queries: three tiles
+        return np.concatenate([rng.integers(3328, 4352, 300), rng.integers(0, m, 200)])
+    if case == "short_batch":  # fewer queries than a tile
+        return rng.integers(0, m, 100)
+    if case == "edge_blocks":  # the first block's clipped start, the last block
+        return np.concatenate([rng.integers(0, 300, 200), rng.integers(m - 300, m, 200)])
+    raise ValueError(case)
+
+
+def _table(cfg, fq, fr):
+    """A QF holding ``fq``/``fr`` fingerprints."""
+    return qf.insert_batch(
+        cfg,
+        qf.empty(cfg),
+        jnp.asarray(fq, jnp.int32),
+        jnp.asarray(fr, jnp.uint32),
+        jnp.ones((len(fq),), jnp.bool_),
+    )
+
+
+@pytest.mark.parametrize(
+    "case", ["sparse", "crowded_block", "short_batch", "edge_blocks"]
+)
+def test_window_aligned_tiles_all_fit(case):
+    cfg = qf.QFConfig(q=14, r=16)
+    rng = np.random.default_rng(5)
+    fq = _quotients(case, cfg.m, rng)
+    fr = rng.integers(0, 2**16, fq.size)
+    if case == "sparse":
+        assert not _numpy_tile_spans(fq, cfg.total_slots).any()
+    # load ~1/4, holding every other query
+    bq, br = rng.integers(0, cfg.m, 4096), rng.integers(0, 2**16, 4096)
+    st = _table(cfg, np.concatenate([bq, fq[::2]]), np.concatenate([br, fr[::2]]))
+    assert _probe_stats(cfg, st, fq, fr) == dict(
+        queries=fq.size,
+        tiles=_numpy_aligned_tiles(fq, cfg.total_slots),
+        tiles_unfit=0,
+        queries_exact=0,
+        exact_passes=0,
+    )
+
+
+def test_cluster_longer_than_the_margin_takes_the_exact_path():
+    # one run of 1,200 fingerprints from quotient 5000 and a second,
+    # shifted, from 5400: their cluster outruns the windows of the
+    # queries on it, whose tiles still fit
+    cfg = qf.QFConfig(q=14, r=16)
+    fq = np.concatenate([np.full(1200, 5000), np.full(50, 5400)])
+    fr = np.concatenate([np.arange(1200), np.arange(50)])
+    st = _table(cfg, fq, fr)
+    rng = np.random.default_rng(6)
+    q = np.concatenate([fq[::25], rng.integers(0, cfg.m, 300)])
+    absent = (np.arange(50) % 2) * 3000  # every other query on the run misses
+    r = np.concatenate([fr[::25] + absent, rng.integers(0, 2**16, 300)])
+    got = _probe_stats(cfg, st, q, r)
+    assert got["tiles"] == _numpy_aligned_tiles(q, cfg.total_slots)
+    assert got["tiles_unfit"] == 0
+    assert got["queries_exact"] > 0 and got["exact_passes"] == 1
 
 
 def test_reference_stats_count_retries_and_exact_answers():

@@ -97,6 +97,25 @@ def test_qf_probe_lookup_compiles(chip):
     )
 
 
+def test_sparse_lookup_runs_as_several_launches(chip):
+    """``ops.contains`` of a sparse batch: 2^16 queries over a 2^26-slot
+    table may touch one window block each, so the probe's static grid
+    bound (a tile per block besides the full tiles) outgrows one
+    launch's scalar prefetch and the kernel runs as several launches."""
+    cfg = qf.QFConfig(q=Q, r=16)
+    text = (
+        jax.jit(lambda st, k: ops.contains(cfg, st, k, mode=MOSAIC))
+        .lower(_state(chip, cfg), _spec(chip, (BATCH,), jnp.uint32))
+        .compile()
+        .as_text()
+    )
+    blocks = -(-cfg.total_slots // 1024)  # ops.lookup's default window block
+    n_tiles = BATCH // 128 + min(BATCH, blocks)
+    launches = len(dispatch.launches(n_tiles, 2))
+    assert launches > 1
+    assert text.count('custom_call_target="tpu_custom_call"') == launches
+
+
 def test_qf_build_sorted_compiles(chip):
     cfg = qf.QFConfig(q=Q, r=16)
     t = cfg.total_slots
