@@ -129,10 +129,10 @@ def contains(cfg, state, keys, *, with_stats=False):
 
     ``with_stats=True`` returns ``(hits, stats)``: ``stats`` is a dict
     of int32 device scalars that the same program computes, counting
-    how the batch was answered (``"qf"`` only: ``queries``, ``tiles``,
-    ``tiles_unfit``, ``queries_exact``, ``exact_passes``; README
-    "Observability").  Other families raise
-    :class:`UnsupportedOpError`.
+    how the batch was answered (``"qf"``, and ``"cascade"`` under pallas
+    with a count per structure: ``queries``, ``tiles``, ``tiles_unfit``,
+    ``queries_exact``, ``exact_passes``; README "Observability").  Other
+    families raise :class:`UnsupportedOpError`.
     """
     impl = by_cfg(cfg)
     if with_stats:

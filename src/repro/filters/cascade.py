@@ -227,6 +227,7 @@ def _build_level_traced(cfg: CascadeConfig, i: int, allq, allr, total):
     return qf_filter.build_fn(cfg)(tgt, tq, tr, jnp.asarray(total, jnp.int32))
 
 
+@jax.named_scope("cascade.collapse")
 def _collapse_into(cfg: CascadeConfig, state: CascadeState, i: int) -> CascadeState:
     """Merge Q0..Q_i into a fresh Q_i; levels above i empty (paper Fig. 5).
 
@@ -297,7 +298,8 @@ def _maybe_collapse(cfg: CascadeConfig, state: CascadeState, full) -> CascadeSta
 
 @functools.partial(jax.jit, static_argnums=0)
 def _insert_impl(cfg: CascadeConfig, state, keys, k) -> CascadeState:
-    q0 = qf_filter.insert_keys(cfg.q0_cfg, cfg.backend, state.q0, keys, k)
+    with jax.named_scope("cascade.q0"):
+        q0 = qf_filter.insert_keys(cfg.q0_cfg, cfg.backend, state.q0, keys, k)
     state = state._replace(q0=q0)
     full = qf.load(cfg.q0_cfg, q0) >= cfg.max_load
     return _maybe_collapse(cfg, state, full)
@@ -335,28 +337,38 @@ def _level_contains(cfg: CascadeConfig, state, i: int, keys):
     )
 
 
-def _fused_level_hits(cfg: CascadeConfig, state, keys):
+def _fused_level_hits(cfg: CascadeConfig, state, keys, with_stats=False):
     """Per-structure hits from ONE fused kernel pass over the stack.
 
     Q0 and the unfrozen levels hash and sort once and share a single
     multi-window probe grid; frozen levels fold in via their 3-gather
     pass (``ops.cascade_lookup``).  Returns ``(q0_hit, [hit per
     level])`` so ``contains`` can OR and ``probe`` can keep the paper's
-    top-down read accounting without a second pass.
+    top-down read accounting without a second pass; ``with_stats``
+    appends the probe's counters (:func:`contains_stats`).
     """
     from repro.kernels import ops as kernel_ops
 
     qf_ix = [i for i in range(cfg.levels) if not cfg.is_frozen(i)]
     fz_ix = [i for i in range(cfg.levels) if cfg.is_frozen(i)]
-    hits = kernel_ops.cascade_lookup(
+    out = kernel_ops.cascade_lookup(
         (cfg.q0_cfg,) + tuple(cfg.level_cfg(i) for i in qf_ix),
         (state.q0,) + tuple(state.levels[i] for i in qf_ix),
         tuple(cfg.fuse_cfg(i) for i in fz_ix),
         tuple(state.levels[i] for i in fz_ix),
         keys,
+        with_stats=with_stats,
     )
+    hits, stats = out if with_stats else (out, None)
     per_level = dict(zip(qf_ix + fz_ix, hits[1:]))
-    return hits[0], [per_level[i] for i in range(cfg.levels)]
+    lvl_hits = [per_level[i] for i in range(cfg.levels)]
+    if not with_stats:
+        return hits[0], lvl_hits
+    # one count per structure, Q0 first; a frozen level's pass counts none
+    at = jnp.asarray([0] + [1 + i for i in qf_ix])
+    for key in ("tiles_unfit", "queries_exact"):
+        stats[key] = jnp.zeros((1 + cfg.levels,), jnp.int32).at[at].set(stats[key])
+    return hits[0], lvl_hits, stats
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -377,6 +389,26 @@ def contains(cfg: CascadeConfig, state, keys):
     for i in range(cfg.levels):
         hit = hit | _level_contains(cfg, state, i, keys)
     return hit
+
+
+def contains_stats(cfg: CascadeConfig, state, keys):
+    """``(hits, stats)``: ``contains`` and the counters of its fused
+    probe, from the same program (README "Observability"): scalars
+    ``queries``, ``tiles`` and ``exact_passes`` (whole-structure
+    decodes), and ``(1 + levels,)`` arrays ``tiles_unfit`` and
+    ``queries_exact``, one count per structure, Q0 first.  Only the
+    pallas backend runs the fused probe that they count."""
+    if cfg.backend != "pallas":
+        raise UnsupportedOpError(
+            "cascade", "contains_stats", "the counters count backend='pallas'"
+        )
+    return _contains_stats(cfg, state, keys)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _contains_stats(cfg: CascadeConfig, state, keys):
+    q0_hit, lvl_hits, stats = _fused_level_hits(cfg, state, keys, with_stats=True)
+    return functools.reduce(jnp.logical_or, lvl_hits, q0_hit), stats
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -756,6 +788,7 @@ IMPL = register(
         make=make,
         insert=insert,
         contains=contains,
+        contains_stats=contains_stats,
         stats=stats,
         delete=delete,
         merge=merge,

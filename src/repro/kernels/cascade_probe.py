@@ -83,6 +83,7 @@ def cascade_probe_tiles(
     tile_t: int = 128,
     wblk: int = 1024,
     interpret: bool = True,
+    with_stats: bool = False,
 ):
     """Probe all QF levels of a cascade in one fused grid.
 
@@ -92,6 +93,11 @@ def cascade_probe_tiles(
     canonically sorted, tile-padded query batch (so every ``fq_levels[l]``
     is ascending).  Returns ``(hit_mask, ovf_mask)`` int32 (B,) bitmask
     arrays — bit ``l`` of query ``i`` is level ``l``'s verdict/overflow.
+    ``with_stats=True`` adds an int32 ``(L,)`` count of the tiles whose
+    quotients outrun each level's window (all their queries overflow).
+
+    The ``pallas_call`` runs under the ``cascade.kernel`` scope, so a
+    trace can tell the kernel's time from its wrapper's.
     """
     L = len(level_planes)
     if L < 1:
@@ -133,20 +139,25 @@ def cascade_probe_tiles(
             in_specs=in_specs,
             out_specs=[qspec, qspec],
         )
-        return pl.pallas_call(
-            _make_kernel(L),
-            grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)] * 2,
-            interpret=interpret,
-        )(
+        args = (
             *(x[s : s + n] for x in scalars),
             *plane_args,
             *(x[s : s + n] for x in query_args),
         )
+        with jax.named_scope("cascade.kernel"):
+            return pl.pallas_call(
+                _make_kernel(L),
+                grid_spec=grid_spec,
+                out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)] * 2,
+                interpret=interpret,
+            )(*args)
 
     hit3, ovf3 = dispatch.concat_launches(
         launch(s, n) for s, n in dispatch.launches(n_tiles, 2 * L)
     )
 
     ovf3 = ovf3 | tile_mask[:, None, None]
-    return hit3.reshape(B), ovf3.reshape(B)
+    if not with_stats:
+        return hit3.reshape(B), ovf3.reshape(B)
+    unfit = jnp.stack([jnp.sum((tile_mask >> lvl) & 1) for lvl in range(L)])
+    return hit3.reshape(B), ovf3.reshape(B), unfit.astype(jnp.int32)

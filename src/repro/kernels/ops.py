@@ -327,10 +327,10 @@ def fuse_contains(
 @functools.partial(
     jax.jit,
     static_argnums=(0, 1),
-    static_argnames=("mode", "tile_t", "wblk"),
+    static_argnames=("mode", "tile_t", "wblk", "with_stats"),
 )
 def _cascade_lookup(
-    qf_cfgs, fuse_cfgs, qf_states, fuse_states, keys, *, mode, tile_t, wblk
+    qf_cfgs, fuse_cfgs, qf_states, fuse_states, keys, *, mode, tile_t, wblk, with_stats
 ):
     p = qf_cfgs[0].q + qf_cfgs[0].r
     seed = qf_cfgs[0].seed
@@ -349,15 +349,19 @@ def _cascade_lookup(
     fqc, frc = qf.fingerprints(canon, keys)
 
     qf_hits = []
+    L = len(qf_cfgs)
+    B0 = keys.shape[0]
     if not dispatch.is_pallas(mode):
         for c, s in zip(qf_cfgs, qf_states):
             fq, fr = qf._requotient(fqc, frc, canon, c)
             qf_hits.append((s.n > 0) & qf.lookup_exact(c, s, fq, fr))
+        stats = _probe_stats(queries=B0, tiles=0, exact_passes=L)
+        stats["tiles_unfit"] = jnp.zeros((L,), jnp.int32)
+        stats["queries_exact"] = jnp.full((L,), B0, jnp.int32)
     else:
         # one canonical-fingerprint sort serves every level: requotient
         # is monotone, so the batch is simultaneously sorted by each
         # level's quotient
-        B0 = keys.shape[0]
         iota = jnp.arange(B0, dtype=jnp.int32)
         _, _, perm = jax.lax.sort((fqc, frc, iota), num_keys=3, is_stable=False)
         pad = (-B0) % tile_t
@@ -380,29 +384,41 @@ def _cascade_lookup(
                     s.con.astype(jnp.int32),
                 )
             )
-        hitm_s, ovfm_s = cascade_probe_tiles(
+        hitm_s, ovfm_s, tiles_unfit = cascade_probe_tiles(
             planes,
             fq_lv,
             fr_lv,
             tile_t=tile_t,
             wblk=wblk,
             interpret=dispatch.pallas_interpret(mode),
+            with_stats=True,
         )
         hitm, ovfm = dispatch.unpermute(osort, B0, hitm_s, ovfm_s)
 
+        queries_exact, exact_pass = [], []
         for lvl, (c, s) in enumerate(zip(qf_cfgs, qf_states)):
             hit_l = ((hitm >> lvl) & 1) > 0
             ovf_l = ((ovfm >> lvl) & 1) > 0
 
+            @jax.named_scope("cascade.exact")
             def resolve(args, c=c, s=s, lvl=lvl):
                 hit_l, ovf_l = args
                 exact = qf.lookup_exact(c, s, fq_raw[lvl], fr_raw[lvl])
                 return jnp.where(ovf_l, exact, hit_l)
 
+            exact_pass.append(jnp.any(ovf_l))
+            queries_exact.append(jnp.sum(ovf_l, dtype=jnp.int32))
             hit_l = jax.lax.cond(
-                jnp.any(ovf_l), resolve, lambda a: a[0], (hit_l, ovf_l)
+                exact_pass[-1], resolve, lambda a: a[0], (hit_l, ovf_l)
             )
             qf_hits.append((s.n > 0) & hit_l)
+        stats = _probe_stats(
+            queries=B0,
+            tiles=osort.shape[0] // tile_t,
+            exact_passes=jnp.sum(jnp.stack(exact_pass), dtype=jnp.int32),
+        )
+        stats["tiles_unfit"] = tiles_unfit
+        stats["queries_exact"] = jnp.stack(queries_exact)
 
     # frozen levels: their probe positions hash the fingerprint (not
     # monotone in it), so they keep their own position-sorted 3-gather
@@ -411,9 +427,11 @@ def _cascade_lookup(
         fuse_lookup(c, s, fqc, frc, mode=mode)
         for c, s in zip(fuse_cfgs, fuse_states)
     ]
-    return tuple(qf_hits) + tuple(fuse_hits)
+    hits = tuple(qf_hits) + tuple(fuse_hits)
+    return (hits, stats) if with_stats else hits
 
 
+@jax.named_scope("cascade.probe")  # outside the jitted wrapper, as qf.probe
 def cascade_lookup(
     qf_cfgs,
     qf_states,
@@ -425,6 +443,7 @@ def cascade_lookup(
     interpret: bool | None = None,
     tile_t: int = 128,
     wblk: int = 1024,
+    with_stats: bool = False,
 ):
     """Probe a whole cascade stack in one fused pass.
 
@@ -433,6 +452,15 @@ def cascade_lookup(
     share the fingerprint width ``p`` and seed.  Returns one bool (B,)
     hit array per structure, QF structures first, in argument order —
     the caller ORs (contains) or schedules (probe I/O accounting) them.
+
+    ``with_stats=True`` returns ``(hits, stats)``, int32 counters of the
+    QF structures' fused pass computed in the same program: scalars
+    ``queries``, ``tiles`` (query tiles of the fused grid) and
+    ``exact_passes`` (whole-structure decodes), and ``(len(qf_cfgs),)``
+    arrays ``tiles_unfit`` (tiles whose quotients outrun that
+    structure's window) and ``queries_exact`` (queries that structure
+    answered on its exact path).  In xla mode every structure answers
+    every query exactly, with no tiles.
     """
     return _cascade_lookup(
         tuple(qf_cfgs),
@@ -443,6 +471,7 @@ def cascade_lookup(
         mode=dispatch.resolve(mode, interpret),
         tile_t=tile_t,
         wblk=wblk,
+        with_stats=with_stats,
     )
 
 

@@ -204,3 +204,64 @@ def test_build_kernel_keeps_its_name_inside_its_scope(chip):
     for name, op_name in ops_:
         assert name.startswith("%_build_sorted."), name
         assert "qf.build" in op_name.split("/"), op_name
+
+
+def test_cascade_probe_compiles_for_the_paper_cell(chip):
+    """The fused probe of ``paper_cascade_1to24.read_c``: 2^20 queries
+    over Q0 (2^22 slots) and five levels (2^23 .. 2^27 slots) of
+    ``cascade(ram_q=22, p=40, fanout=2, levels=5)``."""
+    cfg, _ = filters.make("cascade", ram_q=22, p=40, fanout=2, levels=5)
+    totals = [cfg.q0_cfg.total_slots] + [
+        cfg.level_cfg(i).total_slots for i in range(cfg.levels)
+    ]
+    interpret = dispatch.pallas_interpret(dispatch.resolve(MOSAIC))
+
+    def probe(planes, fqs, frs):
+        return cascade_probe_tiles(planes, fqs, frs, interpret=interpret)
+
+    q = _spec(chip, (1 << 20,), jnp.int32)
+    _assert_kernel(
+        probe,
+        [_planes(chip, t) for t in totals],
+        [q] * len(totals),
+        [q] * len(totals),
+    )
+
+
+def _cascade_ops(chip, monkeypatch, op):
+    """Kernel ops of a small pallas cascade's insert or lookup program
+    (the cascade's kernels take the process's kernel mode)."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", MOSAIC)
+    make = dict(ram_q=12, p=40, fanout=2, levels=2, backend="pallas")
+    cfg, _ = filters.make("cascade", **make)
+    state = jax.eval_shape(lambda: filters.make("cascade", **make)[1])
+    state = jax.tree.map(lambda s: _spec(chip, s.shape, s.dtype), state)
+    fn = getattr(filters, op)
+    return _kernel_ops(
+        lambda s, k: fn(cfg, s, k), state, _spec(chip, (1024,), jnp.uint32)
+    )
+
+
+def test_cascade_build_kernels_keep_their_name_inside_the_cascade_scopes(
+    chip, monkeypatch
+):
+    """Q0's rebuild and every collapse run the flat ``qf_build`` kernel:
+    its op keeps the ``%_build_sorted.`` name that ``bench/kernels.json``
+    finds, under ``cascade.q0`` or ``cascade.collapse``."""
+    ops_ = _cascade_ops(chip, monkeypatch, "insert")
+    scopes = set()
+    for name, op_name in ops_:
+        assert name.startswith("%_build_sorted."), name
+        path = op_name.split("/")
+        assert "qf.build" in path, op_name
+        scopes |= {"cascade.q0", "cascade.collapse"} & set(path)
+    assert scopes == {"cascade.q0", "cascade.collapse"}
+
+
+def test_cascade_probe_kernel_runs_inside_its_scopes(chip, monkeypatch):
+    ops_ = _cascade_ops(chip, monkeypatch, "contains")
+    assert ops_
+    for name, op_name in ops_:
+        assert not name.startswith(("%_lookup.", "%_build_sorted.")), name
+        path = op_name.split("/")
+        assert path.index("cascade.probe") < path.index("cascade.kernel"), op_name
