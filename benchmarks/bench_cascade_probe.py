@@ -1,17 +1,17 @@
-"""Fused multi-level cascade probe vs the per-level reference walk.
+"""Cascade probe (hash once, sort once) vs the per-level reference walk.
 
 A probe against a cascade must consult Q0 plus every non-empty level.
 The reference backend re-fingerprints the batch per level and walks the
 structures one by one; the pallas backend's ``ops.cascade_lookup``
 hashes once, sorts once (the canonical fingerprint order is
 simultaneously sorted for every level's quotient — requotienting is
-monotone), and probes all unfrozen levels' windows in ONE grid, folding
-frozen (binary-fuse) levels in via their 3-gather pass.
+monotone), and probes each unfrozen structure in its own window-aligned
+tiles, folding frozen (binary-fuse) levels in via their 3-gather pass.
 
-The gated ``kernelratio_cascade_probe`` row is the fused/deployed time
-over the reference walk on a 4-level mixed-frozen stack at 16k probes —
-capped absolutely at ``perf_gate.RATIO_MAX`` so the fused pass can
-never silently regress behind the per-level path it replaces.
+The gated ``kernelratio_cascade_probe`` row is the deployed time over
+the reference walk on a 4-level mixed-frozen stack at 16k probes —
+capped absolutely at ``perf_gate.RATIO_MAX`` so the single-sort pass
+can never silently regress behind the per-level path it replaces.
 """
 
 from __future__ import annotations
@@ -58,8 +58,8 @@ def run() -> list[Row]:
         [keys[: N_PROBES // 2], keys_u32(rng, N_PROBES // 2)]
     )
 
-    # jit both sides: the ratio should compare the fused single-grid
-    # probe against the per-level *algorithm*, not against the eager
+    # jit both sides: the ratio should compare the single-sort probe
+    # against the per-level *algorithm*, not against the eager
     # dispatch overhead of walking five structures op by op
     f_ref = jax.jit(lambda s, p: filters.contains(cfg_r, s, p))
     f_fused = jax.jit(lambda s, p: filters.contains(cfg_p, s, p))

@@ -338,11 +338,11 @@ def _level_contains(cfg: CascadeConfig, state, i: int, keys):
 
 
 def _fused_level_hits(cfg: CascadeConfig, state, keys, with_stats=False):
-    """Per-structure hits from ONE fused kernel pass over the stack.
+    """Per-structure hits from ONE probe program over the stack.
 
-    Q0 and the unfrozen levels hash and sort once and share a single
-    multi-window probe grid; frozen levels fold in via their 3-gather
-    pass (``ops.cascade_lookup``).  Returns ``(q0_hit, [hit per
+    Q0 and the unfrozen levels hash and sort once, then each probes the
+    sorted batch in its own window-aligned tiles; frozen levels fold in
+    via their 3-gather pass (``ops.cascade_lookup``).  Returns ``(q0_hit, [hit per
     level])`` so ``contains`` can OR and ``probe`` can keep the paper's
     top-down read accounting without a second pass; ``with_stats``
     appends the probe's counters (:func:`contains_stats`).
@@ -366,7 +366,7 @@ def _fused_level_hits(cfg: CascadeConfig, state, keys, with_stats=False):
         return hits[0], lvl_hits
     # one count per structure, Q0 first; a frozen level's pass counts none
     at = jnp.asarray([0] + [1 + i for i in qf_ix])
-    for key in ("tiles_unfit", "queries_exact"):
+    for key in ("tiles", "tiles_unfit", "queries_exact"):
         stats[key] = jnp.zeros((1 + cfg.levels,), jnp.int32).at[at].set(stats[key])
     return hits[0], lvl_hits, stats
 
@@ -392,12 +392,13 @@ def contains(cfg: CascadeConfig, state, keys):
 
 
 def contains_stats(cfg: CascadeConfig, state, keys):
-    """``(hits, stats)``: ``contains`` and the counters of its fused
-    probe, from the same program (README "Observability"): scalars
-    ``queries``, ``tiles`` and ``exact_passes`` (whole-structure
-    decodes), and ``(1 + levels,)`` arrays ``tiles_unfit`` and
-    ``queries_exact``, one count per structure, Q0 first.  Only the
-    pallas backend runs the fused probe that they count."""
+    """``(hits, stats)``: ``contains`` and the counters of its probe,
+    from the same program (README "Observability"): scalars ``queries``
+    and ``exact_passes`` (whole-structure decodes), and ``(1 + levels,)``
+    arrays ``tiles`` (live window-aligned query tiles), ``tiles_unfit``
+    and ``queries_exact``, one count per structure, Q0 first (a frozen
+    level counts none).  Only the pallas backend runs the probe that
+    they count."""
     if cfg.backend != "pallas":
         raise UnsupportedOpError(
             "cascade", "contains_stats", "the counters count backend='pallas'"
@@ -419,7 +420,7 @@ def probe(cfg: CascadeConfig, state, keys):
     top-down short-circuit.  Matches ``cost_model.cascade_probe_reads``.
 
     The modeled I/O schedule stays top-down-sequential either way; under
-    the pallas backend the *device* work is the one fused pass of
+    the pallas backend the *device* work is the one probe program of
     ``_fused_level_hits`` and the schedule is re-derived from its
     per-level hits."""
     if cfg.backend == "pallas":

@@ -1,78 +1,37 @@
-"""Pallas TPU kernel: fused multi-level cascade membership probe.
+"""Pallas TPU probe of a cascade's unfrozen structures, each tiled by its
+own windows.
 
-The cascade answers a query by probing Q0 plus every disk level — the
-reference path launches one windowed probe per level, re-reading the
-sorted query tiles L times and paying L grid launches.  This kernel
-fuses the whole unfrozen stack into ONE grid over the sorted queries:
+The cascade answers a query by probing Q0 plus every disk level.  The
+wrapper (``ops.cascade_lookup``) hashes the batch once and sorts it once
+by the canonical p-bit fingerprint: requotienting is monotone, so that
+one order is sorted by every structure's quotient.
 
-* Requotienting is monotone, so sorting queries once by their p-bit
-  canonical fingerprint sorts them by *every* level's quotient
-  simultaneously — one sort serves all levels.
-* The grid is one program per query tile.  For each of the L levels the
-  program sees that level's own 2*wblk-slot window (aligned start
-  scalar-prefetched per (tile, level), exactly ``qf_probe``'s
-  two-consecutive-block scheme and lane-dense layout, just L of them),
-  and runs the shared branch-free cluster decode
-  (``qf_probe.window_decode``) per level.
-* Per-query results come back as two int32 *bitmasks* (hit / overflow,
-  bit l = level l), so the launch has a fixed two-output shape for any
-  static depth L.
+Each structure then groups the sorted queries by its *own* window blocks
+(``qf_probe.window_tiles``) and runs the flat QF's single-window probe
+kernel over them (``qf_probe.qf_probe_tiles``), so every tile fits its
+window at any density.  A structure with ``nbw`` window blocks makes at
+most ``ceil(B / T) + min(B, nbw - 1)`` tiles: a dense one about
+``B / T`` full tiles, a sparse one about one tile per touched block.  A
+single tiling of ``T`` consecutive queries shared by all structures
+would span ``T`` times the deeper structures' mean gap between queries,
+more than their window, and send them to their exact path.
 
-Frozen (binary-fuse) levels cannot join the fused grid — their probe
-positions are hashes of the fingerprint, not monotone in it, so they
-need their own position sort — and are folded in by the wrapper
-(``ops.cascade_lookup``) via the existing 3-gather ``fuse_probe`` pass.
+Per-query answers come back as two int32 *bitmasks* in sorted order
+(hit / overflow, bit l = structure l), so the wrapper unpermutes once
+for any static depth L.  A query whose cluster outruns its window sets
+its structure's overflow bit; the wrapper resolves it on that
+structure's exact path.
 
-Tiles whose quotient span outruns a level's window flag that level's
-overflow bit; the wrapper resolves flagged queries on the exact path,
-per level, preserving bit-exactness with the per-level reference.
+Frozen (binary-fuse) levels are not probed here: their probe positions
+hash the fingerprint, not monotone in it, so the wrapper folds them in
+through their own position-sorted ``fuse_probe`` pass.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from . import dispatch, lanes
-from .qf_probe import window_decode
-
-
-def _make_kernel(L: int):
-    """Kernel body for a static stack depth L.
-
-    Ref layout (positional): 2L scalar-prefetch refs (blk_l, wbase_l
-    interleaved), then 4 window refs per level (rem/meta, two
-    consecutive blocks each), then fq/fr query tiles per level, then
-    the two bitmask outputs.
-    """
-
-    def kernel(*refs):
-        scalars = refs[: 2 * L]
-        planes = refs[2 * L : 6 * L]
-        queries = refs[6 * L : 8 * L]
-        hit_o, ovf_o = refs[8 * L], refs[8 * L + 1]
-        t = pl.program_id(0)
-
-        T = queries[0].shape[-1]
-        hitm = jnp.zeros((T, 1), jnp.int32)
-        ovfm = jnp.zeros((T, 1), jnp.int32)
-        for lvl in range(L):
-            rem_a, rem_b, meta_a, meta_b = planes[4 * lvl : 4 * (lvl + 1)]
-            present, ovf = window_decode(
-                jnp.concatenate([rem_a[...], rem_b[...]], axis=0),
-                jnp.concatenate([meta_a[...], meta_b[...]], axis=0),
-                lanes.row_to_col(queries[2 * lvl][0]),
-                lanes.row_to_col(queries[2 * lvl + 1][0]),
-                scalars[2 * lvl + 1][t],
-            )
-            hitm = hitm | (present.astype(jnp.int32) << lvl)
-            ovfm = ovfm | (ovf.astype(jnp.int32) << lvl)
-        hit_o[0] = lanes.col_to_row(hitm)
-        ovf_o[0] = lanes.col_to_row(ovfm)
-
-    return kernel
+from .qf_probe import qf_probe_tiles
 
 
 def cascade_probe_tiles(
@@ -85,79 +44,39 @@ def cascade_probe_tiles(
     interpret: bool = True,
     with_stats: bool = False,
 ):
-    """Probe all QF levels of a cascade in one fused grid.
+    """Probe every QF structure of a cascade in its own window tiles.
 
     ``level_planes`` is a list of ``(rem, occ, shf, con)`` plane tuples
-    (one per level, arbitrary per-level sizes); ``fq_levels`` /
-    ``fr_levels`` hold each level's quotient/remainder view of the SAME
-    canonically sorted, tile-padded query batch (so every ``fq_levels[l]``
-    is ascending).  Returns ``(hit_mask, ovf_mask)`` int32 (B,) bitmask
-    arrays — bit ``l`` of query ``i`` is level ``l``'s verdict/overflow.
-    ``with_stats=True`` adds an int32 ``(L,)`` count of the tiles whose
-    quotients outrun each level's window (all their queries overflow).
+    (one per structure, arbitrary sizes); ``fq_levels`` / ``fr_levels``
+    hold each structure's quotient/remainder view of the SAME
+    canonically sorted query batch (so every ``fq_levels[l]`` is
+    ascending).  Returns ``(hit_mask, ovf_mask)`` int32 (B,) bitmask
+    arrays — bit ``l`` of query ``i`` is structure ``l``'s
+    verdict/overflow.  ``with_stats=True`` adds two int32 ``(L,)``
+    counts: each structure's live tiles, and those of its tiles whose
+    quotients outrun their window (a guard: 0 by construction).
 
-    The ``pallas_call`` runs under the ``cascade.kernel`` scope, so a
+    The ``pallas_call``s run under the ``cascade.kernel`` scope, so a
     trace can tell the kernel's time from its wrapper's.
     """
-    L = len(level_planes)
-    if L < 1:
+    if not level_planes:
         raise ValueError("cascade_probe_tiles needs at least one level")
-    B = fq_levels[0].shape[0]
-    assert B % tile_t == 0
-    n_tiles = B // tile_t
-
-    scalars = []
-    plane_args = []
-    in_specs = []
-    tile_mask = jnp.zeros((n_tiles,), jnp.int32)
-    query_args = []
-
-    for lvl, (rem, occ, shf, con) in enumerate(level_planes):
-        fq3 = dispatch.query_tiles(fq_levels[lvl], tile_t)
-        blk, wbase, fits = dispatch.window_base(
-            fq3[:, 0, 0], fq3[:, 0, -1], rem.shape[0], wblk, margin=wblk // 4
+    hit_mask = ovf_mask = jnp.zeros(fq_levels[0].shape, jnp.int32)
+    tiles, unfit = [], []
+    for lvl, (planes, fq, fr) in enumerate(zip(level_planes, fq_levels, fr_levels)):
+        present, ovf, live, bad = qf_probe_tiles(
+            *planes,
+            fq,
+            fr,
+            tile_t=tile_t,
+            wblk=wblk,
+            interpret=interpret,
+            scope="cascade.kernel",
         )
-        scalars += [blk, wbase]
-        tile_mask = tile_mask | ((~fits).astype(jnp.int32) << lvl)
-        for plane in (rem, dispatch.meta_plane(occ, shf, con)):
-            padded = dispatch.plane_blocks(plane, wblk)
-            plane_args += [padded, padded]
-            # index_map sees (t, s_0 .. s_{2L-1}); blk of level l is s[2l]
-            in_specs += [
-                dispatch.window_spec(wblk, 0, 2 * lvl),
-                dispatch.window_spec(wblk, 1, 2 * lvl),
-            ]
-        query_args += [fq3, dispatch.query_tiles(fr_levels[lvl], tile_t)]
-
-    qspec = dispatch.query_spec(tile_t)
-    in_specs += [qspec, qspec] * L
-
-    def launch(s, n):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2 * L,
-            grid=(n,),
-            in_specs=in_specs,
-            out_specs=[qspec, qspec],
-        )
-        args = (
-            *(x[s : s + n] for x in scalars),
-            *plane_args,
-            *(x[s : s + n] for x in query_args),
-        )
-        with jax.named_scope("cascade.kernel"):
-            return pl.pallas_call(
-                _make_kernel(L),
-                grid_spec=grid_spec,
-                out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)] * 2,
-                interpret=interpret,
-            )(*args)
-
-    hit3, ovf3 = dispatch.concat_launches(
-        launch(s, n) for s, n in dispatch.launches(n_tiles, 2 * L)
-    )
-
-    ovf3 = ovf3 | tile_mask[:, None, None]
+        hit_mask = hit_mask | (present << lvl)
+        ovf_mask = ovf_mask | (ovf << lvl)
+        tiles.append(live)
+        unfit.append(bad)
     if not with_stats:
-        return hit3.reshape(B), ovf3.reshape(B)
-    unfit = jnp.stack([jnp.sum((tile_mask >> lvl) & 1) for lvl in range(L)])
-    return hit3.reshape(B), ovf3.reshape(B), unfit.astype(jnp.int32)
+        return hit_mask, ovf_mask
+    return hit_mask, ovf_mask, jnp.stack(tiles), jnp.stack(unfit)

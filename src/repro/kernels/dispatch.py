@@ -76,7 +76,7 @@ def pallas_interpret(mode: str) -> bool:
 
 # ---------------------------------------------------------------------------
 # Shared window-prefetch geometry (used by qf_probe / fuse_probe /
-# cascade_probe / bloom_block wrappers)
+# bloom_block wrappers)
 # ---------------------------------------------------------------------------
 
 

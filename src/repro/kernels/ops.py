@@ -320,7 +320,7 @@ def fuse_contains(
 
 
 # ---------------------------------------------------------------------------
-# Fused multi-level cascade probe
+# Cascade probe: every structure of the stack in one program
 # ---------------------------------------------------------------------------
 
 
@@ -343,7 +343,7 @@ def _cascade_lookup(
 
     # hash ONCE in the canonical split; every level's (fq, fr) view is a
     # bit re-split of the same p-bit fingerprint (requotient), so the
-    # fused path never re-hashes per level the way the reference does
+    # probe never re-hashes per level the way the reference does
     qc, rc = ffc.canonical_split(p)
     canon = qf.QFConfig(q=qc, r=rc, slack=0, seed=seed)
     fqc, frc = qf.fingerprints(canon, keys)
@@ -355,27 +355,23 @@ def _cascade_lookup(
         for c, s in zip(qf_cfgs, qf_states):
             fq, fr = qf._requotient(fqc, frc, canon, c)
             qf_hits.append((s.n > 0) & qf.lookup_exact(c, s, fq, fr))
-        stats = _probe_stats(queries=B0, tiles=0, exact_passes=L)
+        stats = _probe_stats(queries=B0, exact_passes=L)
+        stats["tiles"] = jnp.zeros((L,), jnp.int32)
         stats["tiles_unfit"] = jnp.zeros((L,), jnp.int32)
         stats["queries_exact"] = jnp.full((L,), B0, jnp.int32)
     else:
-        # one canonical-fingerprint sort serves every level: requotient
-        # is monotone, so the batch is simultaneously sorted by each
-        # level's quotient
+        # one canonical-fingerprint sort serves every structure:
+        # requotient is monotone, so the sorted batch is sorted by each
+        # structure's quotient too
         iota = jnp.arange(B0, dtype=jnp.int32)
-        _, _, perm = jax.lax.sort((fqc, frc, iota), num_keys=3, is_stable=False)
-        pad = (-B0) % tile_t
-        osort = (
-            jnp.concatenate([perm, jnp.full((pad,), perm[-1])]) if pad else perm
+        fqc_s, frc_s, perm = jax.lax.sort(
+            (fqc, frc, iota), num_keys=3, is_stable=False
         )
-
-        planes, fq_lv, fr_lv, fq_raw, fr_raw = [], [], [], [], []
+        planes, fq_lv, fr_lv = [], [], []
         for c, s in zip(qf_cfgs, qf_states):
-            fq, fr = qf._requotient(fqc, frc, canon, c)
-            fq_raw.append(fq)
-            fr_raw.append(fr)
-            fq_lv.append(fq[osort])
-            fr_lv.append(fr[osort])
+            fq, fr = qf._requotient(fqc_s, frc_s, canon, c)
+            fq_lv.append(fq)
+            fr_lv.append(fr)
             planes.append(
                 (
                     s.rem.astype(jnp.int32),
@@ -384,7 +380,7 @@ def _cascade_lookup(
                     s.con.astype(jnp.int32),
                 )
             )
-        hitm_s, ovfm_s, tiles_unfit = cascade_probe_tiles(
+        hitm_s, ovfm_s, tiles, tiles_unfit = cascade_probe_tiles(
             planes,
             fq_lv,
             fr_lv,
@@ -393,7 +389,7 @@ def _cascade_lookup(
             interpret=dispatch.pallas_interpret(mode),
             with_stats=True,
         )
-        hitm, ovfm = dispatch.unpermute(osort, B0, hitm_s, ovfm_s)
+        hitm, ovfm = dispatch.unpermute(perm, B0, hitm_s, ovfm_s)
 
         queries_exact, exact_pass = [], []
         for lvl, (c, s) in enumerate(zip(qf_cfgs, qf_states)):
@@ -401,9 +397,10 @@ def _cascade_lookup(
             ovf_l = ((ovfm >> lvl) & 1) > 0
 
             @jax.named_scope("cascade.exact")
-            def resolve(args, c=c, s=s, lvl=lvl):
+            def resolve(args, c=c, s=s):
                 hit_l, ovf_l = args
-                exact = qf.lookup_exact(c, s, fq_raw[lvl], fr_raw[lvl])
+                fq, fr = qf._requotient(fqc, frc, canon, c)
+                exact = qf.lookup_exact(c, s, fq, fr)
                 return jnp.where(ovf_l, exact, hit_l)
 
             exact_pass.append(jnp.any(ovf_l))
@@ -414,15 +411,15 @@ def _cascade_lookup(
             qf_hits.append((s.n > 0) & hit_l)
         stats = _probe_stats(
             queries=B0,
-            tiles=osort.shape[0] // tile_t,
             exact_passes=jnp.sum(jnp.stack(exact_pass), dtype=jnp.int32),
         )
+        stats["tiles"] = tiles
         stats["tiles_unfit"] = tiles_unfit
         stats["queries_exact"] = jnp.stack(queries_exact)
 
     # frozen levels: their probe positions hash the fingerprint (not
     # monotone in it), so they keep their own position-sorted 3-gather
-    # pass instead of joining the fused grid
+    # pass instead of the window-tiled QF probe
     fuse_hits = [
         fuse_lookup(c, s, fqc, frc, mode=mode)
         for c, s in zip(fuse_cfgs, fuse_states)
@@ -445,7 +442,8 @@ def cascade_lookup(
     wblk: int = 1024,
     with_stats: bool = False,
 ):
-    """Probe a whole cascade stack in one fused pass.
+    """Probe a whole cascade stack in one program, hashing and sorting
+    the batch once.
 
     ``qf_cfgs``/``qf_states`` are the unfrozen structures top-down (Q0
     first), ``fuse_cfgs``/``fuse_states`` the frozen levels; all must
@@ -454,13 +452,14 @@ def cascade_lookup(
     the caller ORs (contains) or schedules (probe I/O accounting) them.
 
     ``with_stats=True`` returns ``(hits, stats)``, int32 counters of the
-    QF structures' fused pass computed in the same program: scalars
-    ``queries``, ``tiles`` (query tiles of the fused grid) and
-    ``exact_passes`` (whole-structure decodes), and ``(len(qf_cfgs),)``
-    arrays ``tiles_unfit`` (tiles whose quotients outrun that
-    structure's window) and ``queries_exact`` (queries that structure
-    answered on its exact path).  In xla mode every structure answers
-    every query exactly, with no tiles.
+    QF structures' probe computed in the same program: scalars
+    ``queries`` and ``exact_passes`` (whole-structure decodes), and
+    ``(len(qf_cfgs),)`` arrays ``tiles`` (that structure's live
+    window-aligned query tiles), ``tiles_unfit`` (of those, tiles whose
+    quotients outrun their window: a guard, 0 by construction) and
+    ``queries_exact`` (queries that structure answered on its exact
+    path).  In xla mode every structure answers every query exactly,
+    with no tiles.
     """
     return _cascade_lookup(
         tuple(qf_cfgs),

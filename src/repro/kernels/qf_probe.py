@@ -32,6 +32,8 @@ wrapper (ops.py) resolves those on the exact path.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -48,8 +50,7 @@ def window_decode(rem, meta, fq, fr, base):
     ``rem`` / ``meta`` are the ``(rows, 128)`` int32 window planes
     (``meta`` packed by ``dispatch.meta_plane``), ``fq`` / ``fr`` the
     ``(T, 1)`` query columns, ``base`` the window's absolute start slot.
-    Returns ``(present, ovf)`` bool ``(T, 1)`` — shared by the
-    single-level and fused-cascade kernels.
+    Returns ``(present, ovf)`` bool ``(T, 1)``.
     """
     rows, width = rem.shape
     occ = meta & 1
@@ -168,6 +169,7 @@ def qf_probe_tiles(
     tile_t: int = 128,
     wblk: int = 1024,
     interpret: bool = True,
+    scope: str | None = None,
 ):
     """Probe sorted queries in window-aligned tiles (``window_tiles``).
 
@@ -178,6 +180,9 @@ def qf_probe_tiles(
     resolves overflowed queries on its exact path), the number of live
     tiles, and of those whose quotients outrun their window (a guard:
     0 by construction; such a tile would overflow all its queries).
+    ``scope`` names the ``pallas_call``s alone, so that a trace tells
+    the kernel's time from its wrapper's (the cascade's
+    ``cascade.kernel``).
     """
     total = rem.shape[0]
     fq3, fr3, dest, live = window_tiles(fq_sorted, fr_sorted, total, tile_t, wblk)
@@ -206,12 +211,7 @@ def qf_probe_tiles(
             in_specs=[win(0), win(1), win(0), win(1), qspec, qspec],
             out_specs=[qspec, qspec],
         )
-        return pl.pallas_call(
-            _probe_kernel,
-            grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)] * 2,
-            interpret=interpret,
-        )(
+        args = (
             jnp.clip(live - s, 0, n).reshape(1).astype(jnp.int32),
             blk[s : s + n],
             rem2,
@@ -221,6 +221,13 @@ def qf_probe_tiles(
             fq3[s : s + n],
             fr3[s : s + n],
         )
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            return pl.pallas_call(
+                _probe_kernel,
+                grid_spec=grid_spec,
+                out_shape=[jax.ShapeDtypeStruct((n, 1, tile_t), jnp.int32)] * 2,
+                interpret=interpret,
+            )(*args)
 
     # a launch prefetches one block a tile and its live count: at most
     # two words a tile

@@ -112,7 +112,7 @@ def bloom_count_ref(idx_flat, ncells: int):
 def cascade_probe_ref(level_planes, fq_levels, fr_levels, window: int):
     """Multi-level cascade probe oracle: per-level windowed decode
     composed into (hit, ovf) int32 bitmasks (bit l = level l), matching
-    the fused kernel's output contract.
+    ``cascade_probe_tiles``' output contract.
     """
     B = fq_levels[0].shape[0]
     hit = jnp.zeros((B,), jnp.int32)

@@ -21,11 +21,13 @@ import numpy as np
 import pytest
 
 from repro import filters
+from repro.core import quotient_filter as qf
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MAKE = {"ram_q": 10, "p": 40, "fanout": 2, "levels": 5}
 PRELOAD, BATCH, N_BATCHES = 2**13, 2**8, 40
 QUERIES = 4096  # a lookup batch: 1024 loaded, 1024 inserted, 2048 absent
+WINDOW, TILE = 1024, 128  # the probe's window block and query tile
 SCOPES = (
     "cascade.q0",
     "cascade.collapse",
@@ -77,7 +79,7 @@ def _run(backend):
         look = jax.jit(lambda s, k: (filters.contains(cfg, s, k), {}))
     lookup = look.lower(st, jnp.asarray(keys_of(np.arange(QUERIES)))).compile()
     rng = np.random.default_rng(2**31 + 15)
-    out = {"counts": [], "answers": [], "stats": [], "want": []}
+    out = {"counts": [], "answers": [], "stats": [], "want": [], "queries": []}
     stored = 0
     for n_batches in (PRELOAD // BATCH, N_BATCHES):
         for _ in range(n_batches):
@@ -89,6 +91,7 @@ def _run(backend):
         out["answers"].append(np.asarray(hits))
         out["stats"].append(jax.device_get(stats))
         out["want"].append(want)
+        out["queries"].append(keys_of(idx))
     out["compiled"] = ins.as_text() + lookup.as_text()
     return out
 
@@ -139,23 +142,33 @@ def test_backends_agree(pallas_run, reference_run):
         np.testing.assert_array_equal(a, b)
 
 
+def _aligned_tiles(keys, cfg):
+    """Window-aligned tiles of one structure: each sorted query takes the
+    block its window would start at, and a block of ``c`` queries makes
+    ``ceil(c / TILE)`` tiles."""
+    fq = np.asarray(qf.fingerprints(cfg, jnp.asarray(keys))[0])
+    n_blocks = -(-cfg.total_slots // WINDOW) + 1
+    blk = np.clip((fq - WINDOW // 4) // WINDOW, 0, n_blocks - 2)
+    return int((-(-np.unique(blk, return_counts=True)[1] // TILE)).sum())
+
+
 @pytest.mark.parametrize("when", [0, 1], ids=["after_load", "after_cycle"])
 def test_stats_are_consistent(pallas_run, when):
     s = pallas_run["stats"][when]
     structures = 1 + MAKE["levels"]
     assert int(s["queries"]) == QUERIES
-    assert int(s["tiles"]) == QUERIES // 128
-    assert s["tiles_unfit"].shape == s["queries_exact"].shape == (structures,)
-    assert np.all((0 <= s["queries_exact"]) & (s["queries_exact"] <= QUERIES))
-    assert np.all((0 <= s["tiles_unfit"]) & (s["tiles_unfit"] <= int(s["tiles"])))
-    # a structure whose tiles all fit needs no exact pass (no cluster
-    # outruns a 2,048-slot window at load 0.75 or less)
-    fit = s["tiles_unfit"] == 0
-    assert np.all(s["queries_exact"][fit] == 0)
-    assert np.all(s["queries_exact"][~fit] >= 128 * s["tiles_unfit"][~fit])
-    assert int(s["exact_passes"]) == int(np.sum(s["queries_exact"] > 0))
-    # Q0 and the shallow levels are dense enough that every tile fits
-    assert np.all(fit[:3])
+    for key in ("tiles", "tiles_unfit", "queries_exact"):
+        assert s[key].shape == (structures,), key
+    # each structure tiles the batch by its own window blocks
+    cfg, _ = filters.make("cascade", backend="pallas", **MAKE)
+    cfgs = [cfg.q0_cfg] + [cfg.level_cfg(i) for i in range(cfg.levels)]
+    keys = pallas_run["queries"][when]
+    assert list(s["tiles"]) == [_aligned_tiles(keys, c) for c in cfgs]
+    # so every tile fits, and no cluster outruns a 2,048-slot window at
+    # load 0.75 or less: no structure takes an exact pass
+    assert not s["tiles_unfit"].any()
+    assert not s["queries_exact"].any()
+    assert int(s["exact_passes"]) == 0
 
 
 def test_stats_refused_for_the_reference_backend():

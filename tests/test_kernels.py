@@ -226,8 +226,10 @@ class TestXlaLowering:
 
 
 # ---------------------------------------------------------------------------
-# Fused multi-level cascade probe (PR 7 tentpole)
+# Cascade probe: every structure tiled by its own windows
 # ---------------------------------------------------------------------------
+
+WINDOW, TILE = 1024, 128  # ops.cascade_lookup's defaults: wblk, tile_t
 
 
 def _grown_cascade(frozen_below, seed=7, n=3000, backend="pallas"):
@@ -264,6 +266,51 @@ def _per_level_reference(cfg, st, keys):
     return q0, [
         cas._level_contains(ref_cfg, st, i, keys) for i in range(cfg.levels)
     ]
+
+
+def _random_keys(rng, n):
+    return rng.integers(0, 2**32, n, np.int64).astype(np.uint32)
+
+
+def _qf_stack(qs, key_sets, p=40):
+    """QF structures that share ``p`` and the seed, as a cascade's do,
+    each holding its own keys."""
+    cfgs = tuple(qf.QFConfig(q=q, r=p - q) for q in qs)
+    states = tuple(
+        qf.insert(c, qf.empty(c), jnp.asarray(k)) for c, k in zip(cfgs, key_sets)
+    )
+    return cfgs, states
+
+
+def _interpret_matches_xla(cfgs, states, batch):
+    """Answers of the interpreted kernel path, checked structure by
+    structure against the xla lowering; returns them, and the counters."""
+    want = ops.cascade_lookup(cfgs, states, (), (), batch, mode="xla")
+    got, stats = ops.cascade_lookup(
+        cfgs, states, (), (), batch, mode="interpret", with_stats=True
+    )
+    for i, (x, y) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(
+            np.asarray(x), np.asarray(y), err_msg=f"structure {i}"
+        )
+    return np.stack([np.asarray(h) for h in got]), jax.device_get(stats)
+
+
+def _quotients(cfg, keys):
+    return np.sort(np.asarray(qf.fingerprints(cfg, jnp.asarray(keys))[0]))
+
+
+def _window_blocks(fq, total):
+    """The window block each sorted query's tile would start at."""
+    margin = WINDOW // 4
+    return np.clip((fq - margin) // WINDOW, 0, -(-total // WINDOW) - 1)
+
+
+def _aligned_tiles(fq, total):
+    """Window-aligned tiles: a block of ``c`` queries makes
+    ``ceil(c / TILE)`` of them."""
+    _, counts = np.unique(_window_blocks(fq, total), return_counts=True)
+    return int((-(-counts // TILE)).sum())
 
 
 class TestFusedCascadeProbe:
@@ -333,6 +380,57 @@ class TestFusedCascadeProbe:
                 np.testing.assert_array_equal(
                     np.asarray(x), np.asarray(y), err_msg=f"structure {i}"
                 )
+
+    def test_sparse_batch_over_deep_levels_takes_no_exact_pass(self):
+        """256 queries over structures of 2^12 .. 2^16 buckets at load
+        1/2: 128 consecutive sorted queries span more than a window in
+        every structure, and each structure's own window-aligned tiles
+        all fit."""
+        rng = np.random.default_rng(11)
+        qs = (12, 13, 14, 15, 16)
+        stored = [_random_keys(rng, 2 ** (q - 1)) for q in qs]
+        cfgs, states = _qf_stack(qs, stored)
+        present = np.concatenate([k[:26] for k in stored])[:128]
+        absent = _random_keys(rng, 128)
+        batch = jnp.asarray(np.concatenate([present, absent]))
+        for c in cfgs:
+            fq = _quotients(c, batch)
+            # the consecutive 128-query tiles a shared tiling would use
+            blk = _window_blocks(fq[::TILE], c.total_slots)
+            span = fq[TILE - 1 :: TILE] - blk * WINDOW
+            assert np.all(span >= 2 * WINDOW - WINDOW // 4)
+        hits, stats = _interpret_matches_xla(cfgs, states, batch)
+        assert hits.any(axis=0)[:128].all()
+        assert list(stats["tiles"]) == [
+            _aligned_tiles(_quotients(c, batch), c.total_slots) for c in cfgs
+        ]
+        assert not stats["tiles_unfit"].any() and not stats["queries_exact"].any()
+        assert int(stats["exact_passes"]) == 0
+
+    def test_run_longer_than_a_window_takes_that_structures_exact_pass(self):
+        """A structure near ``max_load`` holds 1,800 fingerprints of eight
+        neighbouring quotients: their cluster outruns every window on it,
+        so that structure alone resolves queries on its exact path, and
+        the answers stay exact."""
+        rng = np.random.default_rng(12)
+        qs = (11, 12, 13)
+        cand = _random_keys(rng, 1 << 21)
+        fq = np.asarray(qf.fingerprints(qf.QFConfig(q=12, r=28), jnp.asarray(cand))[0])
+        crowd = cand[(fq >= 1500) & (fq < 1508)]
+        assert crowd.size > 2000
+        spread = [_random_keys(rng, n) for n in (1024, 1200, 4096)]
+        # 3,000 keys in 4,096 buckets: near max_load 0.75
+        stored = [spread[0], np.concatenate([crowd[:1800], spread[1]]), spread[2]]
+        cfgs, states = _qf_stack(qs, stored)
+        present = np.concatenate([crowd[:1800:10], spread[1][:100], spread[2][:100]])
+        absent = np.concatenate([crowd[1800:1900], _random_keys(rng, 100)])
+        hits, stats = _interpret_matches_xla(
+            cfgs, states, jnp.asarray(np.concatenate([present, absent]))
+        )
+        assert hits.any(axis=0)[: present.size].all()
+        assert not stats["tiles_unfit"].any()
+        assert list(stats["queries_exact"] > 0) == [False, True, False]
+        assert int(stats["exact_passes"]) == 1
 
     def test_rejects_mismatched_seeds(self):
         cfg, st, keys, probes = _grown_cascade(None, n=500)

@@ -144,7 +144,7 @@ def test_build_span_compiles(chip):
 
 
 def test_cascade_probe_compiles_for_smoke_geometry(chip):
-    """The fused probe over Q0 plus four levels (2^18 .. 2^26 slots) of
+    """The probe over Q0 plus four levels (2^18 .. 2^26 slots) of
     ``cascade(ram_q=18, p=44, fanout=4, levels=4)``."""
     cfg, _ = filters.make("cascade", ram_q=18, p=44, fanout=4, levels=4)
     totals = [cfg.q0_cfg.total_slots] + [
@@ -207,9 +207,12 @@ def test_build_kernel_keeps_its_name_inside_its_scope(chip):
 
 
 def test_cascade_probe_compiles_for_the_paper_cell(chip):
-    """The fused probe of ``paper_cascade_1to24.read_c``: 2^20 queries
+    """The probe of ``paper_cascade_1to24.read_c``: 2^20 queries
     over Q0 (2^22 slots) and five levels (2^23 .. 2^27 slots) of
-    ``cascade(ram_q=22, p=40, fanout=2, levels=5)``."""
+    ``cascade(ram_q=22, p=40, fanout=2, levels=5)``.  Each structure's
+    window-aligned tiles run as launches of their own, all under
+    ``cascade.kernel``: the deepest level's 2^17 window blocks need
+    several."""
     cfg, _ = filters.make("cascade", ram_q=22, p=40, fanout=2, levels=5)
     totals = [cfg.q0_cfg.total_slots] + [
         cfg.level_cfg(i).total_slots for i in range(cfg.levels)
@@ -220,12 +223,16 @@ def test_cascade_probe_compiles_for_the_paper_cell(chip):
         return cascade_probe_tiles(planes, fqs, frs, interpret=interpret)
 
     q = _spec(chip, (1 << 20,), jnp.int32)
-    _assert_kernel(
+    ops_ = _kernel_ops(
         probe,
         [_planes(chip, t) for t in totals],
         [q] * len(totals),
         [q] * len(totals),
     )
+    assert len(ops_) > len(totals)
+    for name, op_name in ops_:
+        assert not name.startswith("%_lookup."), name
+        assert "cascade.kernel" in op_name.split("/"), op_name
 
 
 def _cascade_ops(chip, monkeypatch, op):
@@ -259,8 +266,11 @@ def test_cascade_build_kernels_keep_their_name_inside_the_cascade_scopes(
 
 
 def test_cascade_probe_kernel_runs_inside_its_scopes(chip, monkeypatch):
+    """Each structure (Q0 and two levels) launches the window-aligned
+    probe under ``cascade.kernel``, and none takes the flat kernel's
+    ``%_lookup.`` name that ``bench/kernels.json`` finds."""
     ops_ = _cascade_ops(chip, monkeypatch, "contains")
-    assert ops_
+    assert len(ops_) == 3
     for name, op_name in ops_:
         assert not name.startswith(("%_lookup.", "%_build_sorted.")), name
         path = op_name.split("/")
